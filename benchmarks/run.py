@@ -207,6 +207,8 @@ def ci_bench(json_path: str) -> None:
 
 
 def main() -> None:
+    from repro.kernels.common import use_compile_cache
+    use_compile_cache()
     from benchmarks import (fig1_consistency_overhead, fig2_update_shipping,
                             fig3_breakdown, fig6_end_to_end,
                             fig7_update_propagation, fig8_consistency,

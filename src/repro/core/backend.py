@@ -242,7 +242,7 @@ class ExecutionBackend(abc.ABC):
         ``corr`` stack's per-bound deltas. This default composes the two
         existing operators (the reference algebra); PallasBackend runs base
         scan and both correction scans as ONE traced launch
-        (scan_filter_agg_group), donating the correction stack."""
+        (scan_filter_agg_group)."""
         fused = self.filter_agg_batch(fcol, acol, bounds)
         if corr is None:
             return fused
@@ -622,8 +622,7 @@ class PallasBackend(NumpyBackend):
         return scan_values_agg(fvals, avals, valid, bounds)
 
     def filter_agg_values_delta(self, corr, bounds):
-        # effective and base correction scans fused into ONE launch; the
-        # freshly built (6, nr) stack is donated on real hardware
+        # effective and base correction scans fused into ONE launch
         return scan_values_delta(corr, bounds)
 
     def filter_agg_delta_batch(self, fcol, acol, bounds, corr):

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import functools
 import os
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -101,6 +102,28 @@ def default_interpret() -> bool:
     real compilation everywhere.
     """
     return kernel_mode() != "compiled"
+
+
+# Lane width of a TPU vector register: the last dim of every kernel block is
+# a multiple of it (or the whole array dim).
+LANES = 128
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache at a fixed directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache (JAX reads it
+    itself) and nothing else is set; otherwise the cache is the repo's own
+    ``.jax_cache/`` (git-ignored). A fixed path matters: the directory is
+    part of the cache key, so a moving one never hits. Returns the
+    directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def next_pow2(n: int) -> int:

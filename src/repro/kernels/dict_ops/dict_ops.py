@@ -1,14 +1,29 @@
-"""Fused analytical-scan kernel (§7): decode -> filter -> aggregate, one pass.
+"""Fused analytical-scan kernel (§7): filter -> aggregate, one pass.
 
 The paper's analytical engine runs scan/filter/aggregate operator instances
 on 1000-tuple segments inside each vault. The PIM win is that the segment
-never leaves the vault. The TPU analog: a grid step pulls one tile of the
-*encoded* filter and aggregate columns into VMEM, applies the code-range
-predicate (the order-preserving-dictionary pushdown — no decode needed for
-the filter), decodes only the selected aggregate codes through the
-VMEM-resident dictionary, and accumulates sum/count — so the HBM traffic is
-exactly one sequential read of each encoded column, matching the vault-local
-single pass of the hardware design.
+never leaves the vault. The TPU analog: a grid step pulls one (rows, 128)
+tile of the filter column, the aggregate values and the validity into VMEM,
+applies every query's range predicate to it, and accumulates exact
+per-block partial sums — one sequential read of each column per query
+group.
+
+Layout. Columns ride as ``(S, n / 128, 128)`` int32 tiles (``S`` stacked
+islands; a flat column is ``S = 1``), so every block is (8, 128)-aligned,
+and the Q query bounds are scalar-prefetched into SMEM. Each grid step
+writes its (4, Q) partials as one output block.
+
+Decode. The dictionary decode (``dict[acodes]``) runs in the surrounding
+XLA program, not in the kernel: Mosaic has no general in-kernel gather,
+while XLA turns a small-dictionary take into a compare/select chain and a
+large one into its native gather. The filter itself needs no decode — the
+order-preserving dictionary turns value ranges into code ranges.
+
+Exactness. Integer sums are accumulated as split 16-bit halves of the
+two's-complement values, per block (each int32 partial is at most
+block * 0xFFFF < 2^31 for block <= 32768); the host reassembles the exact
+int64 total (`ops.assemble_exact`). That is what lets the Pallas backend
+return bit-identical answers to the numpy engine's int64 histogram-dot.
 """
 
 from __future__ import annotations
@@ -18,216 +33,113 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import instrumented_jit
+from repro.kernels.common import LANES, instrumented_jit
+
+MIN_BLOCK = 8 * LANES    # one (8, 128) int32 tile
+MAX_BLOCK = 1 << 15      # keeps every 16-bit partial of a block below 2^31
 
 
-def _scan_exact_kernel(fcodes_ref, acodes_ref, valid_ref, dict_ref, bounds_ref,
-                       lo_ref, hi_ref, cnt_ref, neg_ref):
-    """Multi-query exact variant: Q predicates share one pass over the tile.
+def _make_scan_kernel(nq: int, inclusive: bool):
+    """Kernel body for `nq` predicates; `inclusive` selects lo <= f <= hi
+    (raw-value overlay scans) over the code-range lo <= f < hi."""
 
-    Integer sums are accumulated as split 16-bit halves of the two's-
-    complement representation (per-block partials, so each int32 accumulator
-    holds at most block * 0xFFFF < 2^31); the host reassembles the exact
-    int64 total. This is what lets the Pallas backend return bit-identical
-    answers to the numpy engine, whose aggregate is an int64 histogram-dot.
+    def kernel(bounds_ref, f_ref, a_ref, v_ref, out_ref):
+        f = f_ref[...]                          # (rows, 128) filter column
+        a = a_ref[...]                          # decoded aggregate values
+        ok = v_ref[...] != 0
+        lo16 = a & 0xFFFF                       # low half of u32(a)
+        hi16 = (a >> 16) & 0xFFFF               # high half (mask kills sign)
+        neg = (a < 0).astype(jnp.int32)
+        part = jax.lax.broadcasted_iota(jnp.int32, (4, nq), 0)
+        query = jax.lax.broadcasted_iota(jnp.int32, (4, nq), 1)
+        out = jnp.zeros((4, nq), jnp.int32)
+        for q in range(nq):
+            lo, hi = bounds_ref[q, 0], bounds_ref[q, 1]
+            upper = (f <= hi) if inclusive else (f < hi)
+            m = (f >= lo) & upper & ok
+            sums = (jnp.sum(jnp.where(m, lo16, 0)),
+                    jnp.sum(jnp.where(m, hi16, 0)),
+                    jnp.sum(m.astype(jnp.int32)),
+                    jnp.sum(jnp.where(m, neg, 0)))
+            for k, s in enumerate(sums):
+                out = jnp.where((part == k) & (query == q), s, out)
+        out_ref[...] = out
+
+    return kernel
+
+
+def _scan_partials(bounds, f, a, valid, block: int, inclusive: bool,
+                   interpret: bool):
+    """Traced: (S, n) filter column, aggregate VALUES and validity ->
+    (lo16, hi16, cnt, neg) per-block partials, each (S, n_blocks, Q).
+
+    Rows are padded in-trace to a multiple of the block (valid=0 is the
+    scan identity); blocks below one (8, 128) tile are raised to it.
     """
-    f = fcodes_ref[...]                      # (block,)
-    a = acodes_ref[...]
-    valid = valid_ref[...]
-    b = bounds_ref[...]                      # (Q, 2) code ranges
-    lo = b[:, 0][:, None]
-    hi = b[:, 1][:, None]
-    mask = (f[None, :] >= lo) & (f[None, :] < hi) & (valid[None, :] != 0)
-    m = mask.astype(jnp.int32)               # (Q, block)
-    vals = jnp.take(dict_ref[...], a)        # decode via VMEM dictionary
-    lo16 = (vals & 0xFFFF)[None, :]          # low half of u32(vals)
-    hi16 = ((vals >> 16) & 0xFFFF)[None, :]  # high half (mask kills sign ext)
-    lo_ref[...] = jnp.sum(m * lo16, axis=1, keepdims=True).T
-    hi_ref[...] = jnp.sum(m * hi16, axis=1, keepdims=True).T
-    cnt_ref[...] = jnp.sum(m, axis=1, keepdims=True).T
-    neg_ref[...] = jnp.sum(m * (vals < 0)[None, :].astype(jnp.int32),
-                           axis=1, keepdims=True).T
+    block = max(MIN_BLOCK, block)
+    assert block % MIN_BLOCK == 0 and block <= MAX_BLOCK, block
+    s, n = f.shape
+    v = valid.astype(jnp.int32)
+    pad = (-n) % block
+    if pad:
+        wpad = ((0, 0), (0, pad))
+        f, a, v = jnp.pad(f, wpad), jnp.pad(a, wpad), jnp.pad(v, wpad)
+    n_blocks = (n + pad) // block
+    tiles = [x.astype(jnp.int32).reshape(s, -1, LANES) for x in (f, a, v)]
+    nq = bounds.shape[0]
+    tile = pl.BlockSpec((None, block // LANES, LANES),
+                        lambda i, j, b: (i, j, 0))
+    out = pl.pallas_call(
+        _make_scan_kernel(nq, inclusive),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(s, n_blocks),
+            in_specs=[tile, tile, tile],
+            out_specs=pl.BlockSpec((None, None, 4, nq),
+                                   lambda i, j, b: (i, j, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((s, n_blocks, 4, nq), jnp.int32),
+        interpret=interpret,
+    )(bounds.astype(jnp.int32), *tiles)
+    return tuple(out[:, :, k, :] for k in range(4))
+
+
+def _decode(dictionary, codes):
+    """Dictionary decode in the surrounding XLA program (see module doc)."""
+    return jnp.take(dictionary.astype(jnp.int32), codes, mode="clip")
 
 
 @functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
 def scan_filter_agg_exact_kernel(fcodes, acodes, valid, dictionary, bounds,
                                  block: int = 4096, interpret: bool = True):
-    """Per-block split-sum partials for Q fused queries; combined on host."""
-    (n,) = fcodes.shape
-    assert n % block == 0
-    n_blocks = n // block
-    k = dictionary.shape[0]
-    q = bounds.shape[0]
-    part = jax.ShapeDtypeStruct((n_blocks, q), jnp.int32)
-    return pl.pallas_call(
-        _scan_exact_kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-            pl.BlockSpec((q, 2), lambda i: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, q), lambda i: (i, 0)),
-                   pl.BlockSpec((1, q), lambda i: (i, 0)),
-                   pl.BlockSpec((1, q), lambda i: (i, 0)),
-                   pl.BlockSpec((1, q), lambda i: (i, 0))),
-        out_shape=(part, part, part, part),
-        interpret=interpret,
-    )(fcodes, acodes, valid, dictionary, bounds)
-
-
-def _scan_exact_sharded_kernel(fcodes_ref, acodes_ref, valid_ref, dict_ref,
-                               bounds_ref, lo_ref, hi_ref, cnt_ref, neg_ref):
-    """Leading-shard-axis variant of `_scan_exact_kernel`.
-
-    Grid step (s, i) pulls block i of island s's resident shard; all
-    islands share one launch (the vmapped execution of §4's multiple
-    analytical islands). Padding rows carry valid=0, so a padded slot
-    contributes the exact identity to every accumulator. The same
-    per-block split-16-bit accumulation keeps each int32 partial below
-    2^31; the host reassembles exact int64 per-shard totals.
-    """
-    f = fcodes_ref[0, :]                     # (block,) one shard's tile
-    a = acodes_ref[0, :]
-    valid = valid_ref[0, :]
-    b = bounds_ref[...]                      # (Q, 2) code ranges
-    lo = b[:, 0][:, None]
-    hi = b[:, 1][:, None]
-    mask = (f[None, :] >= lo) & (f[None, :] < hi) & (valid[None, :] != 0)
-    m = mask.astype(jnp.int32)               # (Q, block)
-    vals = jnp.take(dict_ref[...], a)        # decode via VMEM dictionary
-    lo16 = (vals & 0xFFFF)[None, :]
-    hi16 = ((vals >> 16) & 0xFFFF)[None, :]
-    lo_ref[0, 0, :] = jnp.sum(m * lo16, axis=1)
-    hi_ref[0, 0, :] = jnp.sum(m * hi16, axis=1)
-    cnt_ref[0, 0, :] = jnp.sum(m, axis=1)
-    neg_ref[0, 0, :] = jnp.sum(m * (vals < 0)[None, :].astype(jnp.int32),
-                               axis=1)
+    """Per-block split-sum partials, each (n_blocks, Q), for Q EXCLUSIVE
+    code ranges over one flat column; combined on the host."""
+    parts = _scan_partials(bounds, fcodes[None], _decode(dictionary,
+                                                         acodes)[None],
+                           valid[None], block, False, interpret)
+    return tuple(p[0] for p in parts)
 
 
 @functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
 def scan_filter_agg_sharded_kernel(fcodes, acodes, valid, dictionary, bounds,
                                    block: int = 4096, interpret: bool = True):
-    """One launch over (n_shards, width) stacked shards x Q fused queries."""
-    n_shards, width = fcodes.shape
-    assert width % block == 0
-    n_blocks = width // block
-    k = dictionary.shape[0]
-    q = bounds.shape[0]
-    part = jax.ShapeDtypeStruct((n_shards, n_blocks, q), jnp.int32)
-    return pl.pallas_call(
-        _scan_exact_sharded_kernel,
-        grid=(n_shards, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda s, i: (s, i)),
-            pl.BlockSpec((1, block), lambda s, i: (s, i)),
-            pl.BlockSpec((1, block), lambda s, i: (s, i)),
-            pl.BlockSpec((k,), lambda s, i: (0,)),
-            pl.BlockSpec((q, 2), lambda s, i: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, 1, q), lambda s, i: (s, i, 0)),
-                   pl.BlockSpec((1, 1, q), lambda s, i: (s, i, 0)),
-                   pl.BlockSpec((1, 1, q), lambda s, i: (s, i, 0)),
-                   pl.BlockSpec((1, 1, q), lambda s, i: (s, i, 0))),
-        out_shape=(part, part, part, part),
-        interpret=interpret,
-    )(fcodes, acodes, valid, dictionary, bounds)
-
-
-def _scan_values_kernel(fvals_ref, avals_ref, valid_ref, bounds_ref,
-                        lo_ref, hi_ref, cnt_ref, neg_ref):
-    """Raw-value correction scan: the delta-overlay pass of a merged read.
-
-    Same multi-query split-16-bit accumulation as `_scan_exact_kernel`, but
-    the filter column holds raw VALUES (overlay rows are decoded at append
-    time, so the dictionary pushdown does not apply) — bounds are therefore
-    INCLUSIVE value ranges — and the aggregate column is summed directly
-    with no dictionary take.
-    """
-    f = fvals_ref[...]                       # (block,)
-    a = avals_ref[...]
-    valid = valid_ref[...]
-    b = bounds_ref[...]                      # (Q, 2) inclusive value ranges
-    lo = b[:, 0][:, None]
-    hi = b[:, 1][:, None]
-    mask = (f[None, :] >= lo) & (f[None, :] <= hi) & (valid[None, :] != 0)
-    m = mask.astype(jnp.int32)               # (Q, block)
-    lo16 = (a & 0xFFFF)[None, :]
-    hi16 = ((a >> 16) & 0xFFFF)[None, :]
-    lo_ref[...] = jnp.sum(m * lo16, axis=1, keepdims=True).T
-    hi_ref[...] = jnp.sum(m * hi16, axis=1, keepdims=True).T
-    cnt_ref[...] = jnp.sum(m, axis=1, keepdims=True).T
-    neg_ref[...] = jnp.sum(m * (a < 0)[None, :].astype(jnp.int32),
-                           axis=1, keepdims=True).T
+    """One launch over (n_shards, width) stacked shards x Q fused queries:
+    partials of shape (n_shards, n_blocks, Q). Grid step (s, i) scans block
+    i of island s's resident shard; padded slots carry valid=0."""
+    return _scan_partials(bounds, fcodes, _decode(dictionary, acodes),
+                          valid, block, False, interpret)
 
 
 @functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
 def scan_values_agg_exact_kernel(fvals, avals, valid, bounds,
                                  block: int = 4096, interpret: bool = True):
-    """Per-block split-sum partials for Q raw-value queries; host-combined."""
-    (n,) = fvals.shape
-    assert n % block == 0
-    n_blocks = n // block
-    q = bounds.shape[0]
-    part = jax.ShapeDtypeStruct((n_blocks, q), jnp.int32)
-    return pl.pallas_call(
-        _scan_values_kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((q, 2), lambda i: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, q), lambda i: (i, 0)),
-                   pl.BlockSpec((1, q), lambda i: (i, 0)),
-                   pl.BlockSpec((1, q), lambda i: (i, 0)),
-                   pl.BlockSpec((1, q), lambda i: (i, 0))),
-        out_shape=(part, part, part, part),
-        interpret=interpret,
-    )(fvals, avals, valid, bounds)
+    """Raw-value correction scan — the delta-overlay pass of a merged read.
 
-
-def _scan_kernel(fcodes_ref, acodes_ref, valid_ref, dict_ref, bounds_ref,
-                 sum_ref, cnt_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        sum_ref[...] = jnp.zeros_like(sum_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    f = fcodes_ref[...]
-    a = acodes_ref[...]
-    valid = valid_ref[...]
-    lo, hi = bounds_ref[0], bounds_ref[1]
-    mask = (f >= lo) & (f < hi) & (valid != 0)
-    vals = jnp.take(dict_ref[...], a)            # decode via VMEM dictionary
-    contrib = jnp.where(mask, vals.astype(jnp.float32), 0.0)
-    sum_ref[0] += jnp.sum(contrib)
-    cnt_ref[0] += jnp.sum(mask.astype(jnp.int32))
-
-
-@functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
-def scan_filter_agg_kernel(fcodes, acodes, valid, dictionary, bounds,
-                           block: int = 4096, interpret: bool = True):
-    (n,) = fcodes.shape
-    assert n % block == 0
-    k = dictionary.shape[0]
-    return pl.pallas_call(
-        _scan_kernel,
-        grid=(n // block,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-        ],
-        out_specs=(pl.BlockSpec((1,), lambda i: (0,)),
-                   pl.BlockSpec((1,), lambda i: (0,))),
-        out_shape=(jax.ShapeDtypeStruct((1,), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)),
-        interpret=interpret,
-    )(fcodes, acodes, valid, dictionary, bounds)
+    The filter column holds raw VALUES (overlay rows are decoded at append
+    time, so the dictionary pushdown does not apply), so bounds are
+    INCLUSIVE value ranges, and the aggregate values need no decode.
+    Partials are (n_blocks, Q), as `scan_filter_agg_exact_kernel`'s.
+    """
+    parts = _scan_partials(bounds, fvals[None], avals[None], valid[None],
+                           block, True, interpret)
+    return tuple(p[0] for p in parts)

@@ -224,57 +224,28 @@ def apply_sort_merge(old, vals):
     return svals, _bitonic_merge_network(jnp.concatenate(parts, axis=1))
 
 
-# Jitted fused entry points. Each has a donated twin: the *_donated variant
-# gives XLA the freshly-built per-call input stack (the correction overlay
-# stack / the apply stack) for in-place reuse. Selection happens in the ops
-# wrappers via common.donation_enabled() — donated only in compiled mode,
-# where XLA honors donation (XLA:CPU ignores it and warns). Both twins share
-# one trace-count label per pipeline, so the zero-retrace accounting is
-# donation-agnostic.
+# Jitted fused entry points. The query-group programs return per-block
+# partials, so no output can alias the per-call correction stack and it is
+# not donated; the apply pipeline's sorted values alias its value stack, so
+# that one has a donated twin, selected in the ops wrapper via
+# common.donation_enabled() — donated only in compiled mode, where XLA
+# honors donation (XLA:CPU ignores it and warns). Both twins share one
+# trace-count label, so the zero-retrace accounting is donation-agnostic.
 
 scan_group_lowered = functools.partial(instrumented_jit,
                                        static_argnames=("block", "cblock"),
                                        name="scan_group_lowered")(
     scan_group_partials)
-scan_group_lowered_donated = functools.partial(
-    instrumented_jit, static_argnames=("block", "cblock"),
-    donate_argnums=(5,), name="scan_group_lowered")(scan_group_partials)
 
 scan_group_sharded_lowered = functools.partial(
     instrumented_jit, static_argnames=("block", "cblock"),
     name="scan_group_sharded_lowered")(scan_group_sharded_partials)
-scan_group_sharded_lowered_donated = functools.partial(
-    instrumented_jit, static_argnames=("block", "cblock"),
-    donate_argnums=(5,), name="scan_group_sharded_lowered")(
-    scan_group_sharded_partials)
 
 scan_values_delta_lowered = functools.partial(
     instrumented_jit, static_argnames=("cblock",),
-    name="scan_values_delta_lowered")(scan_values_delta_partials)
-scan_values_delta_lowered_donated = functools.partial(
-    instrumented_jit, static_argnames=("cblock",), donate_argnums=(0,),
     name="scan_values_delta_lowered")(scan_values_delta_partials)
 
 apply_pipeline_lowered = instrumented_jit(
     apply_sort_merge, name="apply_pipeline_lowered")
 apply_pipeline_lowered_donated = instrumented_jit(
     apply_sort_merge, donate_argnums=(1,), name="apply_pipeline_lowered")
-
-
-@functools.partial(instrumented_jit, static_argnames=("block",))
-def scan_float_lowered(fcodes, acodes, valid, dictionary, bounds,
-                       block: int = 4096):
-    """Lowering of the legacy float32 scan: per-block sums, then a block
-    reduction (the kernel accumulates block partials sequentially; callers
-    tolerance-test this path, unlike the exact integer partials above)."""
-    fcodes, acodes, v = pad_rows_flat(fcodes, acodes, valid, block)
-    n = fcodes.shape[0]
-    nb = n // block
-    f = fcodes.reshape(nb, block)
-    a = acodes.reshape(nb, block)
-    v = v.reshape(nb, block)
-    mask = (f >= bounds[0]) & (f < bounds[1]) & (v != 0)
-    vals = jnp.take(dictionary, a)
-    contrib = jnp.where(mask, vals.astype(jnp.float32), 0.0)
-    return (jnp.sum(contrib, axis=1).sum()[None],
-            jnp.sum(mask.astype(jnp.int32))[None])

@@ -18,35 +18,30 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import (ISLAND_AXIS, island_spec,
                                         replicated_spec)
 from repro.kernels.bitonic_sort.bitonic_sort import (bitonic_merge_rows,
                                                      bitonic_sort_rows)
-from repro.kernels.common import (donation_enabled, instrumented_jit,
+from repro.kernels.common import (LANES, donation_enabled, instrumented_jit,
                                   kernel_mode, lanes_to_int64, next_pow2,
                                   psum_split16, width_bucket)
 from repro.kernels.dict_ops.dict_ops import (scan_filter_agg_exact_kernel,
-                                             scan_filter_agg_kernel,
                                              scan_filter_agg_sharded_kernel,
                                              scan_values_agg_exact_kernel)
 from repro.kernels.dict_ops.lowered import (apply_pipeline_lowered,
                                             apply_pipeline_lowered_donated,
-                                            pad_rows_flat, pad_rows_sharded,
+                                            pad_rows_sharded,
                                             scan_exact_lowered,
                                             scan_exact_sharded_lowered,
                                             scan_exact_sharded_partials,
-                                            scan_float_lowered,
                                             scan_group_lowered,
-                                            scan_group_lowered_donated,
                                             scan_group_sharded_lowered,
-                                            scan_group_sharded_lowered_donated,
                                             scan_values_delta_lowered,
-                                            scan_values_delta_lowered_donated,
                                             scan_values_lowered)
 from repro.kernels.dict_ops.ref import (scan_filter_agg_batch_ref,
                                         scan_filter_agg_ref,
@@ -106,15 +101,8 @@ def scan_exact_dispatch(fcodes, acodes, valid, dictionary, bounds,
     if mode == "lowered":
         return scan_exact_lowered(fcodes, acodes, valid, dictionary, bounds,
                                   block=block)
-    n = fcodes.shape[0]
-    pad = (-n) % block
-    v = valid.astype(jnp.int32)
-    if pad:
-        fcodes = jnp.pad(fcodes, (0, pad), constant_values=_I32_MAX)
-        acodes = jnp.pad(acodes, (0, pad))
-        v = jnp.pad(v, (0, pad))
-    return scan_filter_agg_exact_kernel(fcodes, acodes, v, dictionary,
-                                        jnp.asarray(bounds), block=block,
+    return scan_filter_agg_exact_kernel(fcodes, acodes, valid, dictionary,
+                                        bounds, block=block,
                                         interpret=(mode == "interpret"))
 
 
@@ -127,16 +115,8 @@ def scan_exact_sharded_dispatch(fcodes, acodes, valid, dictionary, bounds,
     if mode == "lowered":
         return scan_exact_sharded_lowered(fcodes, acodes, valid, dictionary,
                                           bounds, block=block)
-    width = fcodes.shape[1]
-    pad = (-width) % block
-    v = valid.astype(jnp.int32)
-    if pad:
-        wpad = ((0, 0), (0, pad))
-        fcodes = jnp.pad(fcodes, wpad)
-        acodes = jnp.pad(acodes, wpad)
-        v = jnp.pad(v, wpad)
-    return scan_filter_agg_sharded_kernel(fcodes, acodes, v, dictionary,
-                                          jnp.asarray(bounds), block=block,
+    return scan_filter_agg_sharded_kernel(fcodes, acodes, valid, dictionary,
+                                          bounds, block=block,
                                           interpret=(mode == "interpret"))
 
 
@@ -145,35 +125,16 @@ def scan_filter_agg(fcodes, acodes, valid, dictionary, code_lo, code_hi,
                     exact: bool = False):
     """sum(dict[acodes]) and count over rows with code_lo <= fcodes < code_hi.
 
-    exact=True routes through the split-accumulator kernel and returns exact
-    python ints (the execution-backend path); the default keeps the original
-    float32 accumulation.
+    exact=True returns exact python ints (the execution-backend path); the
+    default returns the sum as float32, cast from the same exact scan.
     """
-    if exact:
-        [(s, c)] = scan_filter_agg_batch(fcodes, acodes, valid, dictionary,
-                                         [(code_lo, code_hi)],
-                                         use_pallas=use_pallas, block=block)
-        return s, c
-    if not use_pallas:
+    if not use_pallas and not exact:
         return scan_filter_agg_ref(fcodes, acodes, valid, dictionary,
                                    code_lo, code_hi)
-    bounds = np.asarray([code_lo, code_hi], dtype=np.int32)
-    mode = kernel_mode()
-    if mode == "lowered":
-        s, c = scan_float_lowered(fcodes, acodes, valid, dictionary, bounds,
-                                  block=block)
-        return s[0], c[0]
-    (n,) = fcodes.shape
-    pad = (-n) % block
-    v = valid.astype(jnp.int32)
-    if pad:
-        fcodes = jnp.pad(fcodes, (0, pad), constant_values=_I32_MAX)
-        acodes = jnp.pad(acodes, (0, pad))
-        v = jnp.pad(v, (0, pad))
-    s, c = scan_filter_agg_kernel(fcodes, acodes, v, dictionary,
-                                  jnp.asarray(bounds), block=block,
-                                  interpret=(mode == "interpret"))
-    return s[0], c[0]
+    [(s, c)] = scan_filter_agg_batch(fcodes, acodes, valid, dictionary,
+                                     [(code_lo, code_hi)],
+                                     use_pallas=use_pallas, block=block)
+    return (s, c) if exact else (np.float32(s), c)
 
 
 def scan_filter_agg_batch(fcodes, acodes, valid, dictionary, bounds,
@@ -270,15 +231,15 @@ def scan_values_agg(fvals, avals, valid, bounds, use_pallas: bool = True,
 # Pallas-mode fused bodies: same composition as the lowered twins in
 # lowered.py, but each constituent scan runs through its pallas_call kernel
 # inside ONE outer traced program (the established hash_probe join-scan
-# idiom). The *_donated twins donate the per-call correction/apply stacks —
-# selected via common.donation_enabled(); see the donation-policy note in
-# kernels/common.py.
+# idiom). Only the apply pipeline has a donated twin (its sorted values
+# alias the value stack) — selected via common.donation_enabled(); see the
+# donation-policy note in kernels/common.py.
 
 def _scan_group_kernel_body(fcodes, acodes, valid, dictionary, bounds, corr,
                             vbounds, block, cblock, interpret):
-    fc, ac, v = pad_rows_flat(fcodes, acodes, valid, block)
-    base = scan_filter_agg_exact_kernel(fc, ac, v, dictionary, bounds,
-                                        block=block, interpret=interpret)
+    base = scan_filter_agg_exact_kernel(fcodes, acodes, valid, dictionary,
+                                        bounds, block=block,
+                                        interpret=interpret)
     eff = scan_values_agg_exact_kernel(corr[0], corr[1], corr[2], vbounds,
                                        block=cblock, interpret=interpret)
     neg = scan_values_agg_exact_kernel(corr[3], corr[4], corr[5], vbounds,
@@ -289,9 +250,9 @@ def _scan_group_kernel_body(fcodes, acodes, valid, dictionary, bounds, corr,
 def _scan_group_sharded_kernel_body(fcodes, acodes, valid, dictionary,
                                     bounds, corr, vbounds, block, cblock,
                                     interpret):
-    fc, ac, v = pad_rows_sharded(fcodes, acodes, valid, block)
-    base = scan_filter_agg_sharded_kernel(fc, ac, v, dictionary, bounds,
-                                          block=block, interpret=interpret)
+    base = scan_filter_agg_sharded_kernel(fcodes, acodes, valid, dictionary,
+                                          bounds, block=block,
+                                          interpret=interpret)
     eff = scan_values_agg_exact_kernel(corr[0], corr[1], corr[2], vbounds,
                                        block=cblock, interpret=interpret)
     neg = scan_values_agg_exact_kernel(corr[3], corr[4], corr[5], vbounds,
@@ -311,7 +272,9 @@ def _apply_pipeline_kernel_body(old, vals, interpret):
     rows, w_old = old.shape
     w_val = vals.shape[1]
     svals = bitonic_sort_rows(vals, block_rows=8, interpret=interpret)
-    w_merge = next_pow2(w_old + w_val)
+    # the merge kernel runs at >= one lane-width; the extra width widens
+    # the all-sentinel gap, which keeps each row bitonic
+    w_merge = max(LANES, next_pow2(w_old + w_val))
     parts = [old]
     gap = w_merge - w_old - w_val
     if gap:
@@ -326,22 +289,12 @@ _GROUP_STATICS = ("block", "cblock", "interpret")
 _scan_group_kernel = functools.partial(
     instrumented_jit, static_argnames=_GROUP_STATICS,
     name="scan_group_kernel")(_scan_group_kernel_body)
-_scan_group_kernel_donated = functools.partial(
-    instrumented_jit, static_argnames=_GROUP_STATICS, donate_argnums=(5,),
-    name="scan_group_kernel")(_scan_group_kernel_body)
 _scan_group_sharded_kernel = functools.partial(
     instrumented_jit, static_argnames=_GROUP_STATICS,
-    name="scan_group_sharded_kernel")(_scan_group_sharded_kernel_body)
-_scan_group_sharded_kernel_donated = functools.partial(
-    instrumented_jit, static_argnames=_GROUP_STATICS, donate_argnums=(5,),
     name="scan_group_sharded_kernel")(_scan_group_sharded_kernel_body)
 _scan_values_delta_kernel = functools.partial(
     instrumented_jit, static_argnames=("cblock", "interpret"),
     name="scan_values_delta_kernel")(_scan_values_delta_kernel_body)
-_scan_values_delta_kernel_donated = functools.partial(
-    instrumented_jit, static_argnames=("cblock", "interpret"),
-    donate_argnums=(0,), name="scan_values_delta_kernel")(
-    _scan_values_delta_kernel_body)
 _apply_pipeline_kernel = functools.partial(
     instrumented_jit, static_argnames=("interpret",),
     name="apply_pipeline_kernel")(_apply_pipeline_kernel_body)
@@ -355,10 +308,7 @@ def _padded_corr(corr):
 
     Overlay sizes vary per round, so padding happens on the host with
     `width_bucket` (floor 8) to bound the traced shapes; the padded lanes
-    carry valid=0, the scan identity. Returns (stack, cblock). A freshly
-    padded stack is safe to donate; when nr already sits on its bucket the
-    CALLER's array flows through — engine builds correction stacks fresh
-    per group, so that is safe too (and documented on the backend hooks).
+    carry valid=0, the scan identity. Returns (stack, cblock).
     """
     corr = (np.zeros((6, 8), dtype=np.int32) if corr is None
             else np.asarray(corr, dtype=np.int32))
@@ -394,16 +344,12 @@ def scan_filter_agg_group(fcodes, acodes, valid, dictionary, code_bounds,
     dpad = pad_dictionary_pow2(dictionary)
     mode = kernel_mode()
     if mode == "lowered":
-        fn = (scan_group_lowered_donated if donation_enabled()
-              else scan_group_lowered)
-        parts = fn(fcodes, acodes, valid, dpad, barr, cstack, varr,
-                   block=block, cblock=cblock)
+        parts = scan_group_lowered(fcodes, acodes, valid, dpad, barr,
+                                   cstack, varr, block=block, cblock=cblock)
     else:
-        fn = (_scan_group_kernel_donated if donation_enabled()
-              else _scan_group_kernel)
-        parts = fn(fcodes, acodes, valid, dpad, barr, cstack, varr,
-                   block=block, cblock=cblock,
-                   interpret=(mode == "interpret"))
+        parts = _scan_group_kernel(fcodes, acodes, valid, dpad, barr,
+                                   cstack, varr, block=block, cblock=cblock,
+                                   interpret=(mode == "interpret"))
     bs, bc = assemble_exact(*parts[0:4], axis=0)
     es, ec = assemble_exact(*parts[4:8], axis=0)
     gs, gc = assemble_exact(*parts[8:12], axis=0)
@@ -432,16 +378,14 @@ def scan_filter_agg_group_sharded(fcodes, acodes, valid, dictionary,
     dpad = pad_dictionary_pow2(dictionary)
     mode = kernel_mode()
     if mode == "lowered":
-        fn = (scan_group_sharded_lowered_donated if donation_enabled()
-              else scan_group_sharded_lowered)
-        parts = fn(fcodes, acodes, valid, dpad, barr, cstack, varr,
-                   block=block, cblock=cblock)
+        parts = scan_group_sharded_lowered(fcodes, acodes, valid, dpad,
+                                           barr, cstack, varr, block=block,
+                                           cblock=cblock)
     else:
-        fn = (_scan_group_sharded_kernel_donated if donation_enabled()
-              else _scan_group_sharded_kernel)
-        parts = fn(fcodes, acodes, valid, dpad, barr, cstack, varr,
-                   block=block, cblock=cblock,
-                   interpret=(mode == "interpret"))
+        parts = _scan_group_sharded_kernel(fcodes, acodes, valid, dpad,
+                                           barr, cstack, varr, block=block,
+                                           cblock=cblock,
+                                           interpret=(mode == "interpret"))
     bs, bc = assemble_exact(*parts[0:4], axis=1)    # (n_shards, Q)
     es, ec = assemble_exact(*parts[4:8], axis=0)    # (Q,)
     gs, gc = assemble_exact(*parts[8:12], axis=0)
@@ -466,14 +410,10 @@ def scan_values_delta(corr, vbounds, use_pallas: bool = True):
     varr = pad_bounds_pow2(vbounds)
     mode = kernel_mode()
     if mode == "lowered":
-        fn = (scan_values_delta_lowered_donated if donation_enabled()
-              else scan_values_delta_lowered)
-        parts = fn(cstack, varr, cblock=cblock)
+        parts = scan_values_delta_lowered(cstack, varr, cblock=cblock)
     else:
-        fn = (_scan_values_delta_kernel_donated if donation_enabled()
-              else _scan_values_delta_kernel)
-        parts = fn(cstack, varr, cblock=cblock,
-                   interpret=(mode == "interpret"))
+        parts = _scan_values_delta_kernel(cstack, varr, cblock=cblock,
+                                          interpret=(mode == "interpret"))
     es, ec = assemble_exact(*parts[0:4], axis=0)
     gs, gc = assemble_exact(*parts[4:8], axis=0)
     return [(int(es[q] - gs[q]), int(ec[q] - gc[q])) for q in range(nq)]
@@ -545,25 +485,25 @@ def _mesh_scan_call(mesh, block: int, mode: str):
     already cross-island totals — O(1) host work regardless of islands.
     """
     def body(fcodes, acodes, valid, dictionary, bounds):
-        fc, ac, v = pad_rows_sharded(fcodes, acodes, valid, block)
         if mode == "lowered":
+            fc, ac, v = pad_rows_sharded(fcodes, acodes, valid, block)
             parts = scan_exact_sharded_partials(fc, ac, v, dictionary,
                                                 bounds, block)
         else:
             parts = scan_filter_agg_sharded_kernel(
-                fc, ac, v, dictionary, bounds, block=block,
+                fcodes, acodes, valid, dictionary, bounds, block=block,
                 interpret=(mode == "interpret"))
         out = []
         for p in parts:          # local (1, nb, Q) -> psum'd (nb, Q) lanes
             out.extend(psum_split16(p[0], ISLAND_AXIS))
         return tuple(out)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(island_spec(), island_spec(), island_spec(),
                   replicated_spec(), replicated_spec()),
         out_specs=(P(None, None),) * 8,
-        check_rep=False)  # pallas_call has no replication rule
+        check_vma=False)  # pallas_call has no replication rule
     return instrumented_jit(smapped, name="scan_exact_mesh")
 
 

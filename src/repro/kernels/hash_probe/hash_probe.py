@@ -5,11 +5,13 @@ runs 4 probe units in parallel over linked-list buckets, with a reorder
 buffer to preserve commit order. Pointer chasing has no efficient TPU
 analogue (DESIGN.md §2), so the TPU-native layout replaces linked buckets
 with *fixed-slot open buckets*: a (n_buckets, slots) keys table and a
-matching values table, both VMEM-resident. A probe hashes a block of query
-keys (modulo hash, like the paper), gathers each query's bucket row, and
-compares all slots vector-wide — the "4 concurrent probe units" become a
-128-lane compare. Commit order is preserved for free: outputs stay in
-query order (no reorder buffer needed — noted as an adaptation win).
+matching values table. A probe hashes each query key (modulo hash, like the
+paper) and fetches its bucket row in the surrounding XLA program — Mosaic
+has no general in-kernel gather — and the kernel compares all slots of a
+lane-dense (slots, block) tile of bucket rows vector-wide: the "4
+concurrent probe units" become a 128-lane compare. Commit order is
+preserved for free: outputs stay in query order (no reorder buffer needed
+— noted as an adaptation win).
 """
 
 from __future__ import annotations
@@ -19,84 +21,54 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import instrumented_jit
+from repro.kernels.common import LANES, instrumented_jit
 
 EMPTY = jnp.int32(-2147483648)  # reserved empty-slot key
 
 
-def _probe_kernel(q_ref, tk_ref, tv_ref, default_ref, out_ref):
-    q = q_ref[...]                      # (blk,) query keys
-    tk = tk_ref[...]                    # (n_buckets, slots)
-    tv = tv_ref[...]
-    default = default_ref[0]
-    n_buckets = tk.shape[0]
-    bucket = jax.lax.rem(q, n_buckets)  # the paper's modulo hash
-    bucket = jnp.where(bucket < 0, bucket + n_buckets, bucket)
-    bk = jnp.take(tk, bucket, axis=0)   # (blk, slots) gathered bucket rows
-    bv = jnp.take(tv, bucket, axis=0)
-    hit = bk == q[:, None]              # vector-wide slot compare
-    val = jnp.max(jnp.where(hit, bv, jnp.iinfo(jnp.int32).min), axis=1)
-    out_ref[...] = jnp.where(hit.any(axis=1), val, default)
-
-
-def _probe_sharded_kernel(q_ref, tk_ref, tv_ref, default_ref, out_ref):
-    """Leading-batch-axis probe: grid step (s, i) probes island s's block i
-    against the shared (replicated-dictionary) table — all islands' lookups
-    in one launch."""
-    q = q_ref[0, :]                     # (blk,) one island's query tile
-    tk = tk_ref[...]
-    tv = tv_ref[...]
-    default = default_ref[0]
-    n_buckets = tk.shape[0]
-    bucket = jax.lax.rem(q, n_buckets)
-    bucket = jnp.where(bucket < 0, bucket + n_buckets, bucket)
-    bk = jnp.take(tk, bucket, axis=0)
-    bv = jnp.take(tv, bucket, axis=0)
-    hit = bk == q[:, None]
-    val = jnp.max(jnp.where(hit, bv, jnp.iinfo(jnp.int32).min), axis=1)
-    out_ref[0, :] = jnp.where(hit.any(axis=1), val, default)
-
-
-@functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
-def probe_table_sharded(queries, table_keys, table_vals, default,
-                        block: int = 1024, interpret: bool = True):
-    """Probe a (n_shards, width) stacked query batch in one launch."""
-    n_shards, width = queries.shape
-    assert width % block == 0
-    nb, slots = table_keys.shape
-    return pl.pallas_call(
-        _probe_sharded_kernel,
-        grid=(n_shards, width // block),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda s, i: (s, i)),
-            pl.BlockSpec((nb, slots), lambda s, i: (0, 0)),
-            pl.BlockSpec((nb, slots), lambda s, i: (0, 0)),
-            pl.BlockSpec((1,), lambda s, i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda s, i: (s, i)),
-        out_shape=jax.ShapeDtypeStruct((n_shards, width), table_vals.dtype),
-        interpret=interpret,
-    )(queries, table_keys, table_vals, default)
+def _probe_kernel(default_ref, q_ref, bk_ref, bv_ref, out_ref):
+    q = q_ref[...]                      # (1, blk) query keys
+    bk = bk_ref[...]                    # (slots, blk) their bucket rows
+    hit = bk == q                       # vector-wide slot compare
+    val = jnp.max(jnp.where(hit, bv_ref[...], jnp.iinfo(jnp.int32).min),
+                  axis=0, keepdims=True)
+    found = jnp.max(hit.astype(jnp.int32), axis=0, keepdims=True) > 0
+    out_ref[...] = jnp.where(found, val, default_ref[0])
 
 
 @functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
 def probe_table(queries, table_keys, table_vals, default, block: int = 1024,
                 interpret: bool = True):
-    """Probe `queries` against the bucketed table; miss -> default."""
+    """Probe `queries` (n,) against the bucketed table; miss -> default.
+    Queries are padded in-trace to a multiple of the (>= 128-lane) block."""
     (n,) = queries.shape
-    assert n % block == 0
-    nb, slots = table_keys.shape
-    return pl.pallas_call(
+    block = max(block, LANES)
+    pad = (-n) % block
+    q = jnp.pad(queries, (0, pad))[None, :]
+    n_buckets, slots = table_keys.shape
+    bucket = jax.lax.rem(q[0], n_buckets)   # the paper's modulo hash
+    bucket = jnp.where(bucket < 0, bucket + n_buckets, bucket)
+    bk, bv = (jnp.take(t, bucket, axis=0).T for t in (table_keys, table_vals))
+    row = pl.BlockSpec((1, block), lambda i, d: (0, i))
+    rows = pl.BlockSpec((slots, block), lambda i, d: (0, i))
+    out = pl.pallas_call(
         _probe_kernel,
-        grid=(n // block,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((nb, slots), lambda i: (0, 0)),   # whole table in VMEM
-            pl.BlockSpec((nb, slots), lambda i: (0, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), table_vals.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=((n + pad) // block,),
+            in_specs=[row, rows, rows], out_specs=row),
+        out_shape=jax.ShapeDtypeStruct(q.shape, table_vals.dtype),
         interpret=interpret,
-    )(queries, table_keys, table_vals, default)
+    )(default, q, bk, bv)
+    return out[0, :n]
+
+
+@functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
+def probe_table_sharded(queries, table_keys, table_vals, default,
+                        block: int = 1024, interpret: bool = True):
+    """Probe a (n_shards, width) stacked query batch in one launch — the
+    probe is elementwise, so the islands' batches ride one flat probe."""
+    out = probe_table(queries.reshape(-1), table_keys, table_vals, default,
+                      block=block, interpret=interpret)
+    return out.reshape(queries.shape)

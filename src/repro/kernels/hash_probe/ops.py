@@ -18,15 +18,15 @@ from __future__ import annotations
 import dataclasses
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import (ISLAND_AXIS, island_spec,
                                         replicated_spec)
-from repro.kernels.common import (donation_enabled, instrumented_jit,
-                                  kernel_mode, next_pow2, psum_split16)
+from repro.kernels.common import (instrumented_jit, kernel_mode, next_pow2,
+                                  psum_split16)
 from repro.kernels.dict_ops.dict_ops import (scan_filter_agg_exact_kernel,
                                              scan_filter_agg_sharded_kernel,
                                              scan_values_agg_exact_kernel)
@@ -369,15 +369,9 @@ _JG_STATICS = ("block", "cblock_a", "cblock_j")
 _join_group_lowered = functools.partial(
     instrumented_jit, static_argnames=_JG_STATICS,
     name="join_group_lowered")(_join_group_body)
-_join_group_lowered_donated = functools.partial(
-    instrumented_jit, static_argnames=_JG_STATICS, donate_argnums=(8, 9),
-    name="join_group_lowered")(_join_group_body)
 _join_group_pallas = functools.partial(
     instrumented_jit, static_argnames=_JG_STATICS + ("interpret",),
     name="join_group_kernel")(_join_group_pallas_body)
-_join_group_pallas_donated = functools.partial(
-    instrumented_jit, static_argnames=_JG_STATICS + ("interpret",),
-    donate_argnums=(8, 9), name="join_group_kernel")(_join_group_pallas_body)
 
 
 def scan_filter_agg_join_group(fcodes, acodes, jcodes, fvalid, jvalid,
@@ -408,14 +402,12 @@ def scan_filter_agg_join_group(fcodes, acodes, jcodes, fvalid, jvalid,
             pad_bounds_pow2(code_bounds), ca, cj, pad_bounds_pow2(vbounds))
     mode = kernel_mode()
     if mode == "lowered":
-        fn = (_join_group_lowered_donated if donation_enabled()
-              else _join_group_lowered)
-        parts = fn(*args, block=block, cblock_a=cblock_a, cblock_j=cblock_j)
+        parts = _join_group_lowered(*args, block=block, cblock_a=cblock_a,
+                                    cblock_j=cblock_j)
     else:
-        fn = (_join_group_pallas_donated if donation_enabled()
-              else _join_group_pallas)
-        parts = fn(*args, block=block, cblock_a=cblock_a, cblock_j=cblock_j,
-                   interpret=(mode == "interpret"))
+        parts = _join_group_pallas(*args, block=block, cblock_a=cblock_a,
+                                   cblock_j=cblock_j,
+                                   interpret=(mode == "interpret"))
     sums, counts = assemble_exact(*parts[0:4], axis=0)
     jsums, _ = assemble_exact(*parts[4:8], axis=0)
     aes, aec = assemble_exact(*parts[8:12], axis=0)
@@ -454,11 +446,11 @@ def _mesh_join_call(mesh, block: int, mode: str):
             out.extend(psum_split16(p[0], ISLAND_AXIS))
         return tuple(out)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(island_spec(),) * 5 + (replicated_spec(),) * 3,
         out_specs=(P(None, None),) * 16,
-        check_rep=False)  # pallas_call has no replication rule
+        check_vma=False)  # pallas_call has no replication rule
     return instrumented_jit(smapped, name="scan_exact_join_mesh")
 
 

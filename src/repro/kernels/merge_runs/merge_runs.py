@@ -7,7 +7,9 @@ identity: if A is ascending and B is ascending, then concat(A, reverse(B))
 is *bitonic*, and a bitonic MERGE network (log2(n) stages, not the full
 log^2 sort) sorts it. So an 8-way merge becomes 3 rounds of pairwise
 bitonic merges — the same comparator-tree depth as the hardware unit, with
-every stage a vector-wide reshape+min/max in VMEM.
+every stage vector-wide in VMEM (a reshape + select in the XLA lowering, a
+lane rotation + select in the kernel; the reversal and concatenation run in
+the surrounding XLA program, as Mosaic has no lane reversal).
 
 Keys are 64-bit commit ids carried as two int32 lanes — `hi` holds the
 arithmetic high word and `lo` the bias-corrected low word (see ops._split64)
@@ -25,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.bitonic_sort.bitonic_sort import lane_partner
 from repro.kernels.common import instrumented_jit
 
 
@@ -54,24 +57,43 @@ def _merge_stage(hi, lo, idx, j):
     return exchange(ah, bh), exchange(al, bl), exchange(ai, bi)
 
 
+def _bitonic_rows(ah, al, ai, bh, bl, bi):
+    """concat(A, reverse(B)) per lane: bitonic rows of width 2*width."""
+    return tuple(jnp.concatenate([a, b[:, ::-1]], axis=-1)
+                 for a, b in ((ah, bh), (al, bl), (ai, bi)))
+
+
 def _merge_body(ah, al, ai, bh, bl, bi):
     """Traceable merge network: concat(A, reverse(B)) is bitonic, then
     log2(width) compare-exchange stages sort it. Row-independent, so the
     whole-array lowering and the row-tiled kernel agree bit-for-bit."""
-    hi = jnp.concatenate([ah, bh[:, ::-1]], axis=-1)
-    lo = jnp.concatenate([al, bl[:, ::-1]], axis=-1)
-    idx = jnp.concatenate([ai, bi[:, ::-1]], axis=-1)
+    hi, lo, idx = _bitonic_rows(ah, al, ai, bh, bl, bi)
     width = hi.shape[-1]
     for j in range(int(math.log2(width)) - 1, -1, -1):
         hi, lo, idx = _merge_stage(hi, lo, idx, j)
     return hi, lo, idx
 
 
-def _merge_kernel(ah_ref, al_ref, ai_ref, bh_ref, bl_ref, bi_ref,
-                  oh_ref, ol_ref, oi_ref):
-    """Merge two ascending runs (rows, width) -> (rows, 2*width)."""
-    hi, lo, idx = _merge_body(ah_ref[...], al_ref[...], ai_ref[...],
-                              bh_ref[...], bl_ref[...], bi_ref[...])
+def _merge_stage_lanes(hi, lo, idx, j):
+    """`_merge_stage` by lane rotation: lane i meets lane i ^ 2^j; the
+    lower lane keeps the (hi, lo)-smaller triple, the upper the larger
+    (ties keep their own, as the reshape form's strict swap does)."""
+    stride = 1 << j
+    lane = jax.lax.broadcasted_iota(jnp.int32, hi.shape, 1)
+    ph, pl_, pi = (lane_partner(x, lane, stride) for x in (hi, lo, idx))
+    gt = (hi > ph) | ((hi == ph) & (lo > pl_))
+    lt = (hi < ph) | ((hi == ph) & (lo < pl_))
+    lower = (lane & stride) == 0
+    take = (lower & gt) | (~lower & lt)
+    return (jnp.where(take, ph, hi), jnp.where(take, pl_, lo),
+            jnp.where(take, pi, idx))
+
+
+def _merge_kernel(h_ref, l_ref, i_ref, oh_ref, ol_ref, oi_ref):
+    """Sort bitonic (rows, width) key lanes through the merge network."""
+    hi, lo, idx = h_ref[...], l_ref[...], i_ref[...]
+    for j in range(int(math.log2(hi.shape[-1])) - 1, -1, -1):
+        hi, lo, idx = _merge_stage_lanes(hi, lo, idx, j)
     oh_ref[...] = hi
     ol_ref[...] = lo
     oi_ref[...] = idx
@@ -87,36 +109,21 @@ def _merge_pallas(ah, al, ai, bh, bl, bi, block_rows: int = 8,
     """
     rows, width = ah.shape
     assert bh.shape == ah.shape and rows % block_rows == 0
-    grid = (rows // block_rows,)
-    spec = pl.BlockSpec((block_rows, width), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((block_rows, 2 * width), lambda i: (i, 0))
+    spec = pl.BlockSpec((block_rows, 2 * width), lambda i: (i, 0))
     out = jax.ShapeDtypeStruct((rows, 2 * width), jnp.int32)
     return pl.pallas_call(
         _merge_kernel,
-        grid=grid,
-        in_specs=[spec] * 6,
-        out_specs=(out_spec, out_spec, out_spec),
+        grid=(rows // block_rows,),
+        in_specs=[spec] * 3,
+        out_specs=(spec, spec, spec),
         out_shape=(out, out, out),
         interpret=interpret,
-    )(ah, al, ai, bh, bl, bi)
+    )(*_bitonic_rows(ah, al, ai, bh, bl, bi))
 
 
 bitonic_merge_pair = instrumented_jit(
     _merge_pallas, static_argnames=("block_rows", "interpret"),
     name="bitonic_merge_pair")
-
-# Compiled-mode variant: the lanes fed in are freshly padded temporaries
-# (see ops._merge_lane_pair), so their buffers can be donated to the output
-# allocation on real accelerators. CPU/interpret paths skip this — XLA:CPU
-# ignores donation and warns.
-bitonic_merge_pair_donated = instrumented_jit(
-    _merge_pallas, static_argnames=("block_rows", "interpret"),
-    donate_argnums=(0, 1, 2, 3, 4, 5), name="bitonic_merge_pair_donated")
-
-
-bitonic_merge_pair_lowered = instrumented_jit(
-    _merge_body, name="bitonic_merge_pair_lowered")
-
 
 def _merge_lanes_body(lanes):
     """Single-argument lowering: lanes is the (6, rows, width) stack

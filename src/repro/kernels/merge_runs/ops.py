@@ -13,11 +13,8 @@ from __future__ import annotations
 
 import numpy as np
 
-import jax.numpy as jnp
-
 from repro.kernels.common import kernel_mode, next_pow2
 from repro.kernels.merge_runs.merge_runs import (bitonic_merge_pair,
-                                                 bitonic_merge_pair_donated,
                                                  merge_lanes_lowered)
 from repro.kernels.merge_runs.ref import merge_pair_ref, merge_runs_ref
 
@@ -92,12 +89,8 @@ def _merge_lane_pair(ah, al, ai, bh, bl, bi):
         buf = np.full((rows + pad_rows, width), fill, dtype=np.int32)
         buf[:rows, :wlane] = lane
         padded.append(buf)
-    if mode == "compiled":
-        # padded lanes are fresh temporaries -> donate them to the output
-        oh, ol, oi = bitonic_merge_pair_donated(
-            *(jnp.asarray(p) for p in padded), interpret=False)
-    else:
-        oh, ol, oi = bitonic_merge_pair(*padded, interpret=True)
+    oh, ol, oi = bitonic_merge_pair(*padded,
+                                    interpret=(mode == "interpret"))
     # valid entries sort before the sentinels; trim to true length
     return (np.asarray(oh)[:rows, : wa + wb],
             np.asarray(ol)[:rows, : wa + wb],

@@ -6,8 +6,7 @@ vault bandwidth. On TPU, split-transaction tracking is the compiler's job;
 the kernel contribution is (a) VMEM-tiled streaming so the copy runs at
 HBM bandwidth, and (b) a *dirty-chunk* predicate (extending the paper's
 column-granularity lazy snapshotting one level finer): clean chunks are
-carried over from the previous snapshot without being re-read from the
-source, halving traffic for partially-updated columns.
+carried over from the previous snapshot.
 """
 
 from __future__ import annotations
@@ -21,9 +20,8 @@ from jax.experimental import pallas as pl
 from repro.kernels.common import instrumented_jit
 
 
-def _copy_kernel(src_ref, prev_ref, dirty_ref, out_ref):
-    dirty = dirty_ref[0] != 0
-    out_ref[...] = jnp.where(dirty, src_ref[...], prev_ref[...])
+def _copy_kernel(dirty_ref, src_ref, prev_ref, out_ref):
+    out_ref[...] = jnp.where(dirty_ref[...] != 0, src_ref[...], prev_ref[...])
 
 
 @functools.partial(instrumented_jit, static_argnames=("block",))
@@ -47,19 +45,26 @@ def snapshot_copy_lowered(src, prev, dirty, block: int = 8192):
 @functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
 def snapshot_copy_kernel(src, prev, dirty, block: int = 8192,
                          interpret: bool = True):
+    """Chunk-predicated copy of block-padded (n,) columns.
+
+    Each chunk is one row of an (n_chunks, block) view, and its dirty flag
+    one row of an (n_chunks, 1) column, so a grid step selects 8 whole
+    chunks at once; chunk rows are padded to a multiple of 8.
+    """
     (n,) = src.shape
     assert n % block == 0
     n_chunks = n // block
     assert dirty.shape == (n_chunks,)
-    return pl.pallas_call(
+    pad = (-n_chunks) % 8
+    rows = [jnp.pad(x.reshape(n_chunks, -1), ((0, pad), (0, 0)))
+            for x in (dirty[:, None], src, prev)]
+    chunks = pl.BlockSpec((8, block), lambda i: (i, 0))
+    out = pl.pallas_call(
         _copy_kernel,
-        grid=(n_chunks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), src.dtype),
+        grid=((n_chunks + pad) // 8,),
+        in_specs=[pl.BlockSpec((8, 1), lambda i: (i, 0)), chunks, chunks],
+        out_specs=chunks,
+        out_shape=jax.ShapeDtypeStruct((n_chunks + pad, block), src.dtype),
         interpret=interpret,
-    )(src, prev, dirty)
+    )(*rows)
+    return out[:n_chunks].reshape(n)

@@ -12,6 +12,8 @@ Pins the three tentpole contracts of the real-hardware fast path:
   bucketing means warm rounds hit only compiled-cache entries.
 """
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -21,8 +23,10 @@ from repro.core.session import HTAPSession, resolve_spec
 from repro.core.workload import split_stream
 from repro.kernels import common
 from repro.kernels.bitonic_sort import sort_rows
+from repro.kernels.dict_ops import ops as dict_ops
 from repro.kernels.dict_ops import scan_filter_agg
 from repro.kernels.hash_probe import build_table, probe
+from repro.kernels.hash_probe import ops as hash_ops
 from repro.kernels.merge_runs import merge_sorted_pairs, merge_sorted_runs
 from repro.kernels.snapshot_copy import snapshot_copy
 
@@ -71,6 +75,21 @@ def test_kernel_mode_resolution(interpret_mode):
     interpret_mode("auto")
     on_accel = jax.default_backend() in ("tpu", "gpu")
     assert common.kernel_mode() == ("compiled" if on_accel else "lowered")
+
+
+def test_compile_cache_follows_env_else_repo_dir(monkeypatch, tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert common.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # JAX's own
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = common.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(repo, ".jax_cache")
 
 
 def test_override_wins_over_env(monkeypatch, interpret_mode):
@@ -124,6 +143,61 @@ def _family_outputs():
     dirty = np.asarray([1, 0, 1, 1, 0], dtype=np.int32)
     out["snapshot_copy"] = np.asarray(snapshot_copy(src, prev, dirty,
                                                     block=64))
+    out.update(_fused_family_outputs(rng))
+    return out
+
+
+def _fused_family_outputs(rng):
+    """The fused main-path entry points: stacked and flat query groups
+    with delta corrections, join groups, the ship-batch apply pipeline and
+    the stacked probe, each reduced to exact host integers."""
+    out = {}
+    n, k, nq = 700, 40, 3
+    fc, ac, jc = (rng.integers(0, k, size=n).astype(np.int32)
+                  for _ in range(3))
+    fv, jv = rng.random(n) < 0.9, rng.random(n) < 0.8
+    adict = np.sort(rng.choice(np.arange(-(10**6), 10**6), size=k,
+                               replace=False)).astype(np.int32)
+    rcount = rng.integers(0, 50, size=k).astype(np.int32)
+    code_bounds = [(3, 30), (0, k), (9, 9)]
+    vbounds = [(-500, 500), (0, 10**6), (7, 7)]
+    corr = rng.integers(-1000, 1000, size=(6, 50)).astype(np.int32)
+    corr[[2, 5]] = rng.integers(0, 2, size=(2, 50))
+    out["dict_ops/group"] = np.asarray(dict_ops.scan_filter_agg_group(
+        fc, ac, fv, adict, code_bounds, corr, vbounds))
+    out["dict_ops/group_sharded"] = np.asarray(
+        dict_ops.scan_filter_agg_group_sharded(
+            fc[:696].reshape(4, 174), ac[:696].reshape(4, 174),
+            fv[:696].reshape(4, 174), adict, code_bounds, corr, vbounds))
+    out["dict_ops/values_delta"] = np.asarray(
+        dict_ops.scan_values_delta(corr, vbounds))
+    out["dict_ops/sharded"] = np.asarray(dict_ops.scan_filter_agg_sharded(
+        fc[:699].reshape(3, 233), ac[:699].reshape(3, 233),
+        fv[:699].reshape(3, 233), adict, code_bounds))
+    out["hash_probe/join"] = np.asarray(hash_ops.scan_filter_agg_join(
+        fc, ac, jc, fv, jv, adict, rcount, code_bounds))
+    out["hash_probe/join_group"] = np.asarray(
+        hash_ops.scan_filter_agg_join_group(fc, ac, jc, fv, jv, adict,
+                                            rcount, code_bounds, corr, corr,
+                                            vbounds))
+    tkeys = np.unique(rng.integers(0, 5000, size=90)).astype(np.int32)
+    table = build_table(tkeys, np.arange(len(tkeys), dtype=np.int32))
+    batches = [rng.integers(0, 5000, size=m).astype(np.int32)
+               for m in (5, 130, 0)]
+    for i, got in enumerate(hash_ops.probe_sharded(table, batches)):
+        out[f"hash_probe/sharded{i}"] = np.asarray(got)
+    imax = np.iinfo(np.int32).max
+    for w_old, w_val in ((8, 8), (256, 64)):
+        old = np.full((3, w_old), imax, dtype=np.int32)
+        vals = np.full((3, w_val), imax, dtype=np.int32)
+        for r in range(3):
+            o = np.unique(rng.integers(-900, 900, size=w_old - r))
+            old[r, :len(o)] = o
+            vals[r, :w_val - 2 * r] = rng.integers(-900, 900,
+                                                   size=w_val - 2 * r)
+        svals, merged = dict_ops.apply_pipeline_batch(old, vals)
+        out[f"apply/{w_old}/sorted"] = np.asarray(svals)[:, :w_val]
+        out[f"apply/{w_old}/merged"] = np.asarray(merged)[:, :w_old + w_val]
     return out
 
 

@@ -1,0 +1,12 @@
+"""Seconds of JAX tracing, lowering and compiling (or compile-cache
+retrieval) inside the window's ``flush_updates`` and ``query_batch``
+calls."""
+
+from chipbench.stats import overlap_s, window_spans
+
+
+def read(run):
+    spans = window_spans(run, "flush", "query_batch")
+    if not spans:
+        return None
+    return overlap_s(run.compile_intervals, spans)

@@ -30,7 +30,7 @@ import numpy as np
 
 from repro.core.backend import PallasBackend, ShardedBackend, get_backend
 from repro.core.dsm import ColumnDelta, EncodedColumn, shard_bounds
-from repro.core.hwmodel import CostLog
+from repro.core.hwmodel import CostLog, span
 from repro.core.nsm import UPDATE_DTYPE
 from repro.core.schema import VALUE_BYTES
 from repro.kernels.merge_runs import merge_sorted_runs
@@ -259,10 +259,11 @@ def apply_updates(
     write_codes = encode(write_ops["value"])
 
     # Stage 3: sequential re-encode through the index + scatter update codes.
-    new_codes = old_to_new[old_codes].astype(np.int32)
-    new_codes, valid = _apply_row_ops(new_codes, valid, new_dict, mods, ins,
-                                      dels, encode=encode,
-                                      write_set=(write_ops, write_codes))
+    with span("reencode", n=n):
+        new_codes = old_to_new[old_codes].astype(np.int32)
+        new_codes, valid = _apply_row_ops(new_codes, valid, new_dict, mods,
+                                          ins, dels, encode=encode,
+                                          write_set=(write_ops, write_codes))
 
     if cost is not None and m:
         _optimized_apply_cost(cost, on_pim, m, n, k_old, len(new_dict),
@@ -341,21 +342,22 @@ def apply_updates_shards(
     write_codes = inner.encode_values_shards(
         encode, [w["value"] for *_, w in island_ops])
     codes_parts, valid_parts = [], []
-    for s, ((m_s, i_s, d_s, w_s), wc) in enumerate(zip(island_ops,
-                                                       write_codes)):
-        lo, hi = bounds[s], bounds[s + 1]
-        src_lo, src_hi = min(lo, n), min(hi, n)
-        codes_s = old_to_new[old_codes[src_lo:src_hi]].astype(np.int32)
-        valid_s = np.array(old_valid[src_lo:src_hi], copy=True)
-        pad = (hi - lo) - (src_hi - src_lo)
-        if pad:  # rows this island gains from inserts
-            codes_s = np.concatenate([codes_s, np.zeros(pad, np.int32)])
-            valid_s = np.concatenate([valid_s, np.zeros(pad, bool)])
-        codes_s, valid_s = _apply_row_ops(codes_s, valid_s, new_dict,
-                                          m_s, i_s, d_s, encode=encode,
-                                          write_set=(w_s, wc))
-        codes_parts.append(codes_s)
-        valid_parts.append(valid_s)
+    with span("reencode", n=n):
+        for s, ((m_s, i_s, d_s, w_s), wc) in enumerate(zip(island_ops,
+                                                           write_codes)):
+            lo, hi = bounds[s], bounds[s + 1]
+            src_lo, src_hi = min(lo, n), min(hi, n)
+            codes_s = old_to_new[old_codes[src_lo:src_hi]].astype(np.int32)
+            valid_s = np.array(old_valid[src_lo:src_hi], copy=True)
+            pad = (hi - lo) - (src_hi - src_lo)
+            if pad:  # rows this island gains from inserts
+                codes_s = np.concatenate([codes_s, np.zeros(pad, np.int32)])
+                valid_s = np.concatenate([valid_s, np.zeros(pad, bool)])
+            codes_s, valid_s = _apply_row_ops(codes_s, valid_s, new_dict,
+                                              m_s, i_s, d_s, encode=encode,
+                                              write_set=(w_s, wc))
+            codes_parts.append(codes_s)
+            valid_parts.append(valid_s)
 
     if cost is not None and m:
         _optimized_apply_cost(cost, on_pim, m, n, k_old, len(new_dict),

@@ -45,6 +45,7 @@ from __future__ import annotations
 import abc
 import contextlib
 import dataclasses
+import functools
 import os
 import sys
 from typing import Callable, Iterable, Sequence
@@ -54,6 +55,7 @@ import numpy as np
 
 from repro.core.dsm import (EncodedColumn, ShardedView, make_sharded_view,
                             stack_shard_columns)
+from repro.core.hwmodel import span
 from repro.core.nsm import UPDATE_DTYPE
 from repro.distributed import island_mesh, place_shard_arrays
 from repro.kernels.bitonic_sort import sort_1024, sort_rows
@@ -92,6 +94,31 @@ KERNEL_ENTRY_POINTS = ("scan_filter_agg", "scan_filter_agg_batch",
                        "merge_sorted_pairs", "sort_1024", "sort_rows",
                        "snapshot_copy", "scan_values_agg",
                        "scan_values_delta", "apply_pipeline_batch")
+# The entry points among them that scan columns for a query group: each
+# call is a ``scan`` span of the recording CostLog (`_scan_span`).
+SCAN_ENTRY_POINTS = ("scan_filter_agg", "scan_filter_agg_batch",
+                     "scan_filter_agg_group",
+                     "scan_filter_agg_group_sharded",
+                     "scan_filter_agg_sharded", "scan_filter_agg_mesh",
+                     "scan_filter_agg_join", "scan_filter_agg_join_group",
+                     "scan_filter_agg_join_sharded",
+                     "scan_filter_agg_join_mesh", "scan_values_agg",
+                     "scan_values_delta")
+
+
+def _scan_span(entry_point):
+    """A scan entry point whose every call, from dispatch through argument
+    transfer to the host integers it returns, is a ``scan`` span of the
+    recording CostLog (`hwmodel.span`)."""
+    @functools.wraps(entry_point)
+    def call(*args, **kwargs):
+        with span("scan"):
+            return entry_point(*args, **kwargs)
+    return call
+
+
+for _name in SCAN_ENTRY_POINTS:
+    globals()[_name] = _scan_span(globals()[_name])
 
 
 @contextlib.contextmanager

@@ -28,7 +28,10 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import itertools
+import math
+import time
 from collections import defaultdict
+from typing import NamedTuple
 
 GB = 1e9
 
@@ -155,6 +158,31 @@ class TimelineTag:
     meta: dict = dataclasses.field(default_factory=dict)
 
 
+class Span(NamedTuple):
+    """One recorded span of wall time (`CostLog.record_spans`)."""
+
+    name: str          # a timeline node's kind, or a sub-span's name
+    node: str          # the timeline node it belongs to ("" outside any)
+    parent: int        # index in CostLog.spans of the enclosing span, or -1
+    t0: float          # time.perf_counter() at open
+    t1: float          # time.perf_counter() at close (nan while open)
+    n: int | None = None   # work count (updates, rows, queries), if any
+
+
+_NO_SPAN = contextlib.nullcontext()
+# The recording CostLog whose timeline node is open (set and restored by
+# `CostLog.tagged`), for code that holds no CostLog: the backend's scans
+# and stage 3 of an apply reach it through `span`.
+_recorder: "CostLog | None" = None
+
+
+def span(name: str, n: int | None = None):
+    """A span of the recording CostLog whose node is open; a no-op when
+    none is."""
+    log = _recorder
+    return _NO_SPAN if log is None else log.span(name, n=n)
+
+
 class CostLog:
     """Accumulates cost events; merged per (phase, island, resource).
 
@@ -162,6 +190,13 @@ class CostLog:
     core/timeline.py replay the log as a discrete-event schedule instead of
     whole-run phase buckets. Tagging is always on and purely additive: the
     phase-bucket pricing (`HardwareModel.time`) ignores it entirely.
+
+    After `record_spans()` it also records measured wall time: every
+    timeline node, and every sub-span opened with `span`, becomes a `Span`
+    in ``spans`` and a ``repro.<name>`` profiler annotation, so the spans
+    land in a device trace on its own clock. Off by default, when a span
+    costs one attribute check. Spans are not timeline tags: checkpoints
+    never carry them.
     """
 
     def __init__(self):
@@ -169,12 +204,47 @@ class CostLog:
         self.tags: dict[str, TimelineTag] = {}
         self._active_tag: TimelineTag | None = None
         self._seq = itertools.count()
+        self.spans: list[Span] = []
+        self.recording = False
+        self._open_span = -1
+
+    def record_spans(self) -> None:
+        """Record wall-time spans from now on (see the class docstring)."""
+        self.recording = True
+
+    def span(self, name: str, n: int | None = None, node: str | None = None):
+        """A context that records one span named ``name`` while recording
+        is on. ``node`` defaults to the enclosing span's; ``n`` is the
+        span's work count."""
+        if not self.recording:
+            return _NO_SPAN
+        return self._span(name, n, node)
+
+    @contextlib.contextmanager
+    def _span(self, name: str, n: int | None, node: str | None):
+        parent = self._open_span
+        if node is None:
+            node = (self.spans[parent].node if parent >= 0
+                    else self._active_tag.node if self._active_tag else "")
+        i = len(self.spans)
+        import jax.profiler  # here, so that the cost model imports no JAX
+        with jax.profiler.TraceAnnotation(f"repro.{name}"):
+            self.spans.append(Span(name, node, parent, time.perf_counter(),
+                                   math.nan, n))
+            self._open_span = i
+            try:
+                yield
+            finally:
+                self._open_span = parent
+                self.spans[i] = self.spans[i]._replace(t1=time.perf_counter())
 
     @contextlib.contextmanager
     def tagged(self, node: str, kind: str, round: int = -1,
                deps: tuple[str, ...] = (), sync_deps: tuple[str, ...] = (),
                **meta):
-        """Open a timeline node: events added inside belong to it."""
+        """Open a timeline node: events added inside belong to it. While
+        spans are recorded, the node is a span named by its kind."""
+        global _recorder
         if node in self.tags:
             raise ValueError(f"duplicate timeline node {node!r}")
         tag = TimelineTag(node=node, kind=kind, round=round,
@@ -182,10 +252,14 @@ class CostLog:
                           sync_deps=tuple(sync_deps), meta=dict(meta))
         self.tags[node] = tag
         prev, self._active_tag = self._active_tag, tag
+        prev_recorder, _recorder = (_recorder,
+                                    self if self.recording else None)
         try:
-            yield tag
+            with self.span(kind, n=meta.get("n"), node=node):
+                yield tag
         finally:
             self._active_tag = prev
+            _recorder = prev_recorder
 
     def annotate(self, **meta) -> None:
         """Attach metadata to the active timeline node (no-op untagged) —

@@ -79,6 +79,14 @@ class SessionClosedError(RuntimeError):
 # capacity-triggered maintenance shape; the overlay stays small enough that
 # query-time base+overlay merges remain cheap).
 DELTA_CAPACITY_DEFAULT = 4096
+# `HTAPSession.counters` entries that `finish` reports on the MI family,
+# and the stats key each goes under there.
+FINISH_STATS = {"applications": "applications",
+                "snapshots_created": "snapshots",
+                "snapshots_shared": "shared",
+                "views_built": "sharded_views",
+                "views_shared": "views_shared",
+                "views_resident": "views_resident"}
 
 
 def _resolve_delta(spec: "SystemSpec") -> tuple[bool, int]:
@@ -433,17 +441,14 @@ class HTAPSession:
         stats: dict = {}
         concurrent = spec.kind not in ("ideal_txn", "ana_only")
         if spec.kind == "multi_instance":
-            stats = {"applications": self.applications,
-                     "snapshots": self.cons.snapshots_created,
-                     "shared": self.cons.snapshots_shared,
-                     "islands": self.islands,
-                     "placement": getattr(self.be, "placement", "stacked"),
-                     "sharded_views": self.cons.views_built,
-                     "views_shared": self.cons.views_shared,
-                     "views_resident": self.cons.views_resident}
+            counts = self.counters()
+            stats = {key: counts[name]
+                     for name, key in FINISH_STATS.items()}
+            stats["islands"] = self.islands
+            stats["placement"] = getattr(self.be, "placement", "stacked")
             if self.delta_enabled:
-                stats["delta_appends"] = self.delta_appends
-                stats["compactions"] = self.compactions
+                stats["delta_appends"] = counts["delta_appends"]
+                stats["compactions"] = counts["compactions"]
                 stats["delta_live_entries"] = sum(
                     d.n_overlay for d in self._deltas.values())
             if self.resizes:
@@ -465,6 +470,28 @@ class HTAPSession:
                            self.n_txn, self.n_ana, self.results, stats=stats,
                            async_propagation=spec.async_propagation,
                            concurrent_islands=concurrent)
+
+    def counters(self) -> dict:
+        """The session's work counts so far, flat, read without closing
+        it: ``query_groups`` (query-group timeline nodes, every round),
+        ``kernel_traces`` (jit traces of the kernel entry points), and on
+        the MI family ``ships`` and the application, compaction, snapshot
+        and view counts that `finish` reports."""
+        from repro.kernels.common import kernel_trace_counts
+        out = {"query_groups": sum(t.kind == "ana"
+                                   for t in self.cost.tags.values()),
+               "kernel_traces": sum(kernel_trace_counts().values())}
+        if self.spec.kind == "multi_instance":
+            cons = self.cons
+            out.update(ships=self._ship_i, applications=self.applications,
+                       compactions=self.compactions,
+                       delta_appends=self.delta_appends,
+                       snapshots_created=cons.snapshots_created,
+                       snapshots_shared=cons.snapshots_shared,
+                       views_built=cons.views_built,
+                       views_shared=cons.views_shared,
+                       views_resident=cons.views_resident)
+        return out
 
     def abort(self) -> None:
         """Close the session without pricing (no RunResult) — the clean-up
@@ -589,46 +616,54 @@ class HTAPSession:
         # the state a checkpoint captures and crash recovery replays
         from repro.core import elastic
         elastic.maybe_crash(self)
-        logs = self.store.drain_logs(
-            limit=FINAL_LOG_CAPACITY if spec.propagation_on_pim else None)
+        limit = FINAL_LOG_CAPACITY if spec.propagation_on_pim else None
+        n_updates = self.store.pending_updates
         ship_node = f"r{self.round}:ship{self._ship_i}"
-        self._ship_i += 1
-        # in sync timing the batch waits for the txn execution that filled
-        # it; async releases it at its last update's commit time
-        sync_deps = (self._prev_txn,) if self._prev_txn else ()
-        with self.cost.tagged(ship_node, "ship", round=self.round,
-                              sync_deps=sync_deps, islands=self.islands):
-            # the batch's commit-id span and size are annotated on the tag
-            # even when the Ideal baseline suppresses pricing — freshness
-            # and async release times are metadata, not cost
-            buffers = ship_updates(logs, self.store.n_cols, self.cost,
-                                   on_pim=spec.propagation_on_pim,
-                                   backend=self.be,
-                                   price=not spec.zero_cost_propagation)
-        # The whole batch's dictionary stages ride one sorter dispatch and
-        # one merge dispatch (cost events stay per column below — tags are
-        # structural, and the cost model is analytic, not measured). The
-        # delta plane skips the precompute: eligible batches never touch
-        # the dictionary, and the rare fallback stages its own merge.
-        staged = (precompute_apply_stages(self.replica.columns, buffers,
-                                          backend=self.be)
-                  if spec.optimized_application and len(buffers) > 1
-                  and not self.delta_enabled else {})
-        app_cost = (None if (spec.shipping_only
-                             or spec.zero_cost_propagation)
-                    else self.cost)
-        for col_id, entries in buffers.items():
-            if self.delta_enabled:
-                self._apply_column_delta(col_id, entries, ship_node,
-                                         app_cost)
-            else:
-                apply_node = f"{ship_node}:c{col_id}"
-                self._apply_column_eager(col_id, entries, apply_node,
-                                         app_cost, staged.get(col_id),
-                                         deps=(ship_node,))
-                self._vis_node[col_id] = apply_node
-                self._round_prop.append(apply_node)
-                self.applications += 1
+        with self.cost.span("ship_batch", node=ship_node,
+                            n=n_updates if limit is None
+                            else min(n_updates, limit)):
+            with self.cost.span("drain"):
+                logs = self.store.drain_logs(limit=limit)
+            self._ship_i += 1
+            # in sync timing the batch waits for the txn execution that
+            # filled it; async releases it at its last update's commit time
+            sync_deps = (self._prev_txn,) if self._prev_txn else ()
+            with self.cost.tagged(ship_node, "ship", round=self.round,
+                                  sync_deps=sync_deps, islands=self.islands):
+                # the batch's commit-id span and size are annotated on the
+                # tag even when the Ideal baseline suppresses pricing —
+                # freshness and async release times are metadata, not cost
+                buffers = ship_updates(logs, self.store.n_cols, self.cost,
+                                       on_pim=spec.propagation_on_pim,
+                                       backend=self.be,
+                                       price=not spec.zero_cost_propagation)
+            # The whole batch's dictionary stages ride one sorter dispatch
+            # and one merge dispatch (cost events stay per column below —
+            # tags are structural, and the cost model is analytic, not
+            # measured). The delta plane skips the precompute: eligible
+            # batches never touch the dictionary, and the rare fallback
+            # stages its own merge.
+            staged = {}
+            if (spec.optimized_application and len(buffers) > 1
+                    and not self.delta_enabled):
+                with self.cost.span("stages"):
+                    staged = precompute_apply_stages(self.replica.columns,
+                                                     buffers, backend=self.be)
+            app_cost = (None if (spec.shipping_only
+                                 or spec.zero_cost_propagation)
+                        else self.cost)
+            for col_id, entries in buffers.items():
+                if self.delta_enabled:
+                    self._apply_column_delta(col_id, entries, ship_node,
+                                             app_cost)
+                else:
+                    apply_node = f"{ship_node}:c{col_id}"
+                    self._apply_column_eager(col_id, entries, apply_node,
+                                             app_cost, staged.get(col_id),
+                                             deps=(ship_node,))
+                    self._vis_node[col_id] = apply_node
+                    self._round_prop.append(apply_node)
+                    self.applications += 1
 
     def _apply_column_eager(self, col_id: int, entries: np.ndarray,
                             node: str, app_cost, staged_col, deps,
@@ -647,20 +682,24 @@ class HTAPSession:
                 # each island applies its own row range; the round
                 # becomes visible only as a complete shard set
                 # (all-or-none Phase-2 swap)
-                shards = apply_updates_shards(
+                new = apply_updates_shards(
                     old, entries, app_cost,
                     on_pim=spec.propagation_on_pim, backend=self.be,
                     staged=staged_col, phase=phase)
-                self.cons.on_update_shards(col_id, shards)
+                swap = self.cons.on_update_shards
             elif spec.optimized_application:
-                self.cons.on_update(col_id, apply_updates(
+                new = apply_updates(
                     old, entries, app_cost,
                     on_pim=spec.propagation_on_pim, backend=self.be,
-                    staged=staged_col, phase=phase))
+                    staged=staged_col, phase=phase)
+                swap = self.cons.on_update
             else:
                 # the naive software baseline rebuilds a whole column
-                self.cons.on_update(col_id, apply_updates_naive(
-                    old, entries, app_cost, phase=phase))
+                new = apply_updates_naive(old, entries, app_cost,
+                                          phase=phase)
+                swap = self.cons.on_update
+            with self.cost.span("swap"):
+                swap(col_id, new)
 
     def _apply_column_delta(self, col_id: int, entries: np.ndarray,
                             ship_node: str, app_cost) -> None:

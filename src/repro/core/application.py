@@ -14,7 +14,11 @@ Two algorithms, both functionally exact:
        index old_code -> new_code,
     3. re-encode the column through the index (sequential scan, no random
        dictionary lookups) and scatter the update values' new codes at
-       their rows (hash unit prices the update-value encodes).
+       their rows (hash unit prices the update-value encodes). On a
+       device-resident column (`apply_updates_where`) the index is never
+       gathered: the map is monotone, so each old code moves up by the
+       number of new values below it, a compare-and-add on the device
+       (kernels/reencode), and the column stays on the device.
   Random accesses drop from O((n+m)log(n+m)) to O(n+m), which is the claim
   we verify in benchmarks/fig3 and tests/test_update_application.py.
 
@@ -26,10 +30,13 @@ column.
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 
-from repro.core.backend import PallasBackend, ShardedBackend, get_backend
-from repro.core.dsm import ColumnDelta, EncodedColumn, shard_bounds
+from repro.core.backend import (PallasBackend, ShardedBackend, _fits_int32,
+                                get_backend)
+from repro.core.dsm import (ColumnDelta, EncodedColumn, new_values,
+                            shard_bounds)
 from repro.core.hwmodel import CostLog, span
 from repro.core.nsm import UPDATE_DTYPE
 from repro.core.schema import VALUE_BYTES
@@ -101,6 +108,18 @@ def _apply_row_ops(codes: np.ndarray, valid: np.ndarray, new_dict: np.ndarray,
     if len(dels):
         valid[dels["row"]] = False
     return codes, valid
+
+
+def _last_writes(write_ops: np.ndarray, write_codes: np.ndarray):
+    """One ``(row, code)`` per written row, its last write in commit
+    order (`write_ops` is commit-ordered): a device scatter's order over
+    duplicate rows is undefined, so the duplicates go first."""
+    rows = write_ops["row"]
+    if len(rows) < 2:
+        return rows, write_codes
+    _, first_of_reversed = np.unique(rows[::-1], return_index=True)
+    last = len(rows) - 1 - first_of_reversed
+    return rows[last], write_codes[last]
 
 
 def _merge_dictionary_stages_batch(be, per_column):
@@ -231,20 +250,38 @@ def apply_updates(
     merge batching); it MUST have been computed from this column's current
     dictionary and these updates' write values.
     """
+    return apply_updates_where(col, updates, cost, on_pim, backend, staged,
+                               phase)[0]
+
+
+def apply_updates_where(
+    col: EncodedColumn,
+    updates: np.ndarray,
+    cost: CostLog | None = None,
+    on_pim: bool = True,
+    backend=None,
+    staged=None,
+    phase: str = "apply",
+) -> tuple[EncodedColumn, str]:
+    """`apply_updates`, and where its stage 3 ran: ``"device"`` or
+    ``"host"``."""
     be = get_backend(backend)
     if isinstance(be, ShardedBackend) and be.n_shards > 1:
         from repro.core.dsm import concat_columns
         return concat_columns(apply_updates_shards(col, updates, cost,
                                                    on_pim, be,
                                                    staged=staged,
-                                                   phase=phase))
-    old_codes = np.asarray(col.codes)
+                                                   phase=phase)), "host"
     old_dict = np.asarray(col.dictionary)
-    valid = np.array(col.valid, copy=True)
-    n, k_old = old_codes.shape[0], old_dict.shape[0]
+    n, k_old = col.n_rows, old_dict.shape[0]
     mods, ins, dels = _split_ops(updates)
     write_vals = np.concatenate([mods["value"], ins["value"]])
     m = len(updates)
+    resident = (isinstance(be, PallasBackend)
+                and isinstance(col.codes, jax.Array))
+    # a device stage 3 keeps the column's length and int32 values
+    on_device = (resident and not len(ins)
+                 and _fits_int32(mods["value"]))
 
     # Stages 1-2: update-dictionary sort + dictionary merge + old->new
     # index. (hardware: 1024-value bitonic sorter, merge unit; the index
@@ -258,25 +295,37 @@ def apply_updates(
     write_ops = _sorted_write_ops(mods, ins)
     write_codes = encode(write_ops["value"])
 
-    # Stage 3: sequential re-encode through the index + scatter update codes.
+    # Stage 3: sequential re-encode through the index + scatter update
+    # codes — on the device for a device-resident column, where the
+    # result stays; a host column stays host numpy (the jitted kernels
+    # convert it at dispatch).
     with span("reencode", n=n):
-        new_codes = old_to_new[old_codes].astype(np.int32)
-        new_codes, valid = _apply_row_ops(new_codes, valid, new_dict, mods,
-                                          ins, dels, encode=encode,
-                                          write_set=(write_ops, write_codes))
+        if on_device:
+            # the map is monotone: each old code moves up past the new
+            # values inserted below it, so the device never gathers
+            # through `old_to_new`
+            thresholds, _ = new_values(old_dict, update_dict)
+            rows_w, codes_w = _last_writes(write_ops, write_codes)
+            new_codes, valid = be.reencode_resident(
+                col.codes, col.valid, thresholds, rows_w, codes_w,
+                dels["row"])
+        else:
+            new_codes = old_to_new[np.asarray(col.codes)].astype(np.int32)
+            new_codes, valid = _apply_row_ops(
+                new_codes, np.array(col.valid, copy=True), new_dict, mods,
+                ins, dels, encode=encode, write_set=(write_ops, write_codes))
+            if resident:
+                # a resident column's host apply (an insert batch) goes
+                # back to the device
+                new_codes, valid = jax.device_put((new_codes, valid))
 
     if cost is not None and m:
         _optimized_apply_cost(cost, on_pim, m, n, k_old, len(new_dict),
                               len(update_dict), col.bit_width, phase=phase)
 
-    # columns stay host numpy: the jitted kernels convert at dispatch,
-    # which is far cheaper than an eager device_put per column per round
-    return EncodedColumn(
-        codes=np.asarray(new_codes),
-        dictionary=np.asarray(new_dict),
-        valid=np.asarray(valid),
-        version=col.version + 1,
-    )
+    return EncodedColumn(codes=new_codes, dictionary=np.asarray(new_dict),
+                         valid=valid, version=col.version + 1), (
+        "device" if on_device else "host")
 
 
 def apply_updates_shards(

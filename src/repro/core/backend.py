@@ -30,6 +30,8 @@ surface below, so the same drivers can run either on
     update-log / dict merge     kernels/merge_runs
     update-dictionary sort      kernels/bitonic_sort
     snapshot copy               kernels/snapshot_copy
+    stage-3 re-encode           kernels/reencode (device-resident
+                                columns only)
     ==========================  =================================
 
 Every backend must produce *bit-identical* results: the integer query
@@ -50,11 +52,12 @@ import os
 import sys
 from typing import Callable, Iterable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dsm import (EncodedColumn, ShardedView, make_sharded_view,
-                            stack_shard_columns)
+                            new_values, stack_shard_columns)
 from repro.core.hwmodel import span
 from repro.core.nsm import UPDATE_DTYPE
 from repro.distributed import island_mesh, place_shard_arrays
@@ -73,9 +76,16 @@ from repro.kernels.hash_probe import (EMPTY_KEY, build_table, probe,
                                       scan_filter_agg_join_mesh,
                                       scan_filter_agg_join_sharded)
 from repro.kernels.merge_runs import merge_sorted_pairs, merge_sorted_runs
-from repro.kernels.snapshot_copy import snapshot_copy
+from repro.kernels.reencode import reencode_rows
+from repro.kernels.snapshot_copy import dirty_chunks, snapshot_copy
 
 SNAPSHOT_BLOCK = 8192  # copy-unit chunk size (kernels/snapshot_copy default)
+# The largest old dictionary the device merge networks take. A merge
+# network holds a whole (8, width) row tile in VMEM and unrolls log2(width)
+# stages, so its compile grows with the width and stops fitting VMEM at a
+# 2^17-wide merge (a 2^16 old dictionary); larger dictionaries merge by
+# insertion at their new values' thresholds (`_insert_stages`).
+MERGE_NETWORK_MAX_DICT = 4096
 
 # Every kernel entry point this module dispatches to, by the module-global
 # name used at the call site. The kernel-call counters (the tests'
@@ -93,7 +103,8 @@ KERNEL_ENTRY_POINTS = ("scan_filter_agg", "scan_filter_agg_batch",
                        "probe_sharded", "build_table", "merge_sorted_runs",
                        "merge_sorted_pairs", "sort_1024", "sort_rows",
                        "snapshot_copy", "scan_values_agg",
-                       "scan_values_delta", "apply_pipeline_batch")
+                       "scan_values_delta", "apply_pipeline_batch",
+                       "reencode_rows", "dirty_chunks")
 # The entry points among them that scan columns for a query group: each
 # call is a ``scan`` span of the recording CostLog (`_scan_span`).
 SCAN_ENTRY_POINTS = ("scan_filter_agg", "scan_filter_agg_batch",
@@ -794,8 +805,16 @@ class PallasBackend(NumpyBackend):
         Columns the fused pipeline can't take — an empty side (nothing to
         sort or merge), values beyond int32, or values colliding with the
         int32.max sentinel pad — fall back to the compositional default,
-        as does a batch with fewer than two fusable columns. Results are
-        elementwise identical either way."""
+        as does a batch with fewer than two fusable columns. A batch with
+        an old dictionary larger than the merge networks take
+        (`MERGE_NETWORK_MAX_DICT`) merges every column by insertion
+        (`_insert_stages`): it never meets a network shape that a smaller
+        dictionary has not compiled already. Results are elementwise
+        identical either way."""
+        if any(len(o) > MERGE_NETWORK_MAX_DICT for o, _ in per_column):
+            return [self._insert_stages(np.asarray(o), np.asarray(wv))
+                    for o, wv in per_column]
+
         cols = [(np.asarray(o), np.asarray(wv)) for o, wv in per_column]
         imax = np.iinfo(np.int32).max
 
@@ -835,6 +854,29 @@ class PallasBackend(NumpyBackend):
                 out[i] = stage
         return out
 
+    def _insert_stages(self, old_dict, write_vals):
+        """Stages 1-2 of one column by insertion: the update dictionary is
+        the values' sorted set, and the merged dictionary is the old one
+        with the genuinely new values inserted at their thresholds
+        (`dsm.new_values`), an O(k) copy on the host."""
+        upd = np.unique(write_vals).astype(write_vals.dtype)
+        t, new = new_values(old_dict, upd)
+        nd = np.insert(old_dict, t, new.astype(old_dict.dtype))
+        k = np.arange(len(old_dict))
+        old_to_new = k + np.searchsorted(t, k, side="right")
+        return (upd, nd, self.staged_encoder(nd), old_to_new.astype(np.int64))
+
+    def reencode_resident(self, codes, valid, thresholds, write_rows,
+                          write_codes, del_rows):
+        """Stage 3 of the optimized apply on a device-resident column:
+        the re-encode as a compare-and-add against the new values'
+        thresholds, then the row ops, in one launch
+        (kernels/reencode). Returns the new ``(codes, valid)`` device
+        arrays; the inputs stay valid (no donation), so pinned snapshots
+        that alias them keep reading their version."""
+        return reencode_rows(codes, valid, thresholds, write_rows,
+                             write_codes, del_rows)
+
     def make_encoder(self, dictionary):
         d = np.asarray(dictionary)
         if (len(d) == 0 or not _fits_int32(d)
@@ -870,29 +912,32 @@ class PallasBackend(NumpyBackend):
         if n == 0:
             return super().snapshot_column(col, prev)
         n_chunks = (n + SNAPSHOT_BLOCK - 1) // SNAPSHOT_BLOCK
-        src = np.asarray(col.codes)
         if (prev is not None and prev.n_rows == n
                 and (prev.dictionary is col.dictionary  # snapshots alias
                      or np.array_equal(np.asarray(prev.dictionary),
                                        np.asarray(col.dictionary)))):
             # tracking buffer: only chunks that changed since the previous
             # snapshot are fetched from the main replica (codes are only
-            # comparable when the dictionaries match).
-            prev_codes = np.asarray(prev.codes)
-            diff = src != prev_codes
-            dirty = np.zeros(n_chunks, dtype=bool)
-            full = n // SNAPSHOT_BLOCK
-            if full:
-                dirty[:full] = diff[:full * SNAPSHOT_BLOCK].reshape(
-                    full, SNAPSHOT_BLOCK).any(axis=1)
-            if full < n_chunks:
-                dirty[full] = diff[full * SNAPSHOT_BLOCK:].any()
+            # comparable when the dictionaries match). A device-resident
+            # column computes it on the device.
+            if isinstance(col.codes, jax.Array):
+                dirty = dirty_chunks(col.codes, prev.codes,
+                                     block=SNAPSHOT_BLOCK)
+            else:
+                diff = np.asarray(col.codes) != np.asarray(prev.codes)
+                dirty = np.zeros(n_chunks, dtype=bool)
+                full = n // SNAPSHOT_BLOCK
+                if full:
+                    dirty[:full] = diff[:full * SNAPSHOT_BLOCK].reshape(
+                        full, SNAPSHOT_BLOCK).any(axis=1)
+                if full < n_chunks:
+                    dirty[full] = diff[full * SNAPSHOT_BLOCK:].any()
+                dirty = dirty.astype(np.int32)
             prev_arr = prev.codes
         else:
-            dirty = np.ones(n_chunks, dtype=bool)
+            dirty = np.ones(n_chunks, dtype=np.int32)
             prev_arr = col.codes
-        codes = snapshot_copy(col.codes, prev_arr,
-                              dirty.astype(np.int32),
+        codes = snapshot_copy(col.codes, prev_arr, dirty,
                               block=SNAPSHOT_BLOCK)
         return EncodedColumn(codes=codes, dictionary=col.dictionary,
                              valid=col.valid, version=col.version)

@@ -23,11 +23,58 @@ from __future__ import annotations
 import dataclasses
 import itertools
 
+import numpy as np
+
+from repro.core.application import _apply_row_ops, _split_ops
 from repro.core.backend import get_backend
 from repro.core.dsm import (DSMReplica, EncodedColumn, ShardedView,
-                            concat_columns)
+                            concat_columns, new_values)
 from repro.core.hwmodel import CostLog
 from repro.core.schema import VALUE_BYTES
+
+
+class BuildSide:
+    """A self-join's build side for one replica column: each dictionary
+    code's count over the valid rows (int64), kept for a column whose
+    codes live on the device, so that no join reads them back.
+
+    It follows the column through its Phase-2 swaps from the applied
+    batches alone, on a host copy of the column's values and validity: an
+    apply inserts a zero count at each new value's place in the merged
+    dictionary, takes each touched row's old value off its count and
+    puts its new value on. Each version's counts are an array of their
+    own, so a snapshot keeps the counts of the version it pinned.
+    """
+
+    def __init__(self, col: EncodedColumn):
+        codes = np.asarray(col.codes)
+        self.dictionary = np.asarray(col.dictionary)
+        self.values = self.dictionary[codes]
+        self.valid = np.array(col.valid, dtype=bool)
+        self.counts = np.bincount(codes[self.valid],
+                                  minlength=len(self.dictionary))
+        self.version = col.version
+
+    def follow(self, new_col: EncodedColumn, updates: np.ndarray) -> None:
+        """Move to ``new_col``, this column after applying ``updates``."""
+        new_dict = np.asarray(new_col.dictionary)
+        mods, ins, dels = _split_ops(updates)
+        thresholds, _ = new_values(self.dictionary, np.unique(
+            np.concatenate([mods["value"], ins["value"]])))
+        counts = np.insert(self.counts, thresholds, 0)
+        rows = np.unique(updates["row"])
+        self._count(counts, new_dict, rows[rows < len(self.values)], -1)
+        self.values, self.valid = _apply_row_ops(
+            self.values.astype(new_dict.dtype, copy=False), self.valid,
+            new_dict, mods, ins, dels, encode=lambda v: v)
+        self._count(counts, new_dict, rows, 1)
+        self.dictionary, self.counts = new_dict, counts
+        self.version = new_col.version
+
+    def _count(self, counts, dictionary, rows, step: int) -> None:
+        live = rows[self.valid[rows]]
+        np.add.at(counts, np.searchsorted(dictionary, self.values[live]),
+                  step)
 
 
 @dataclasses.dataclass
@@ -35,6 +82,8 @@ class _Version:
     version_id: int
     column: EncodedColumn
     readers: int = 0
+    # the column's `BuildSide` counts at this version, where it keeps one
+    build_counts: np.ndarray | None = None
     # The sharded snapshot plane: islands' resident shards of this
     # version, materialized once at first pinned read (`read_scan`) and
     # reused by every query group pinning the same version. Invalidated —
@@ -108,10 +157,22 @@ class ConsistencyManager:
         # rounds instead of round-tripping concat + re-shard through the
         # host. One pending view per column; superseded by the next swap.
         self._resident: dict[int, ShardedView] = {}
+        # self-join build sides of the columns that keep one
+        # (`keep_build_sides`), each following its column's swaps
+        self.build_sides: dict[int, BuildSide] = {}
+
+    def keep_build_sides(self) -> None:
+        """Keep a `BuildSide` of every replica column from now on: a
+        replica whose codes live on the device then joins without reading
+        them back. A swap given no update batch drops the column's."""
+        self.build_sides = {c: BuildSide(col)
+                            for c, col in self.replica.columns.items()}
 
     # -- transactional side ----------------------------------------------
-    def on_update(self, col_id: int, new_col: EncodedColumn) -> None:
+    def on_update(self, col_id: int, new_col: EncodedColumn,
+                  updates=None) -> None:
         """Phase-2 pointer swap: install the new column, mark dirty.
+        ``updates``, the batch applied, moves the column's build side.
 
         The swap also invalidates every *unpinned* ShardedView of this
         column's snapshots: the next pinned read will snapshot + re-shard
@@ -120,6 +181,12 @@ class ConsistencyManager:
         still pinned by in-flight queries stay valid — that is snapshot
         isolation — until their readers finish and GC drops the version.
         """
+        side = self.build_sides.get(col_id)
+        if side is not None:
+            if updates is None:
+                del self.build_sides[col_id]  # a swap it cannot follow
+            else:
+                side.follow(new_col, updates)
         self.replica.columns[col_id] = new_col
         self.chains[col_id].dirty = True
         self._resident.pop(col_id, None)  # superseded before adoption
@@ -198,7 +265,11 @@ class ConsistencyManager:
         head = self.chains[col_id].head
         snap = self.backend.snapshot_column(
             col, prev=head.column if head is not None else None)
-        v = _Version(version_id=next(self._version_ids), column=snap)
+        side = self.build_sides.get(col_id)
+        v = _Version(version_id=next(self._version_ids), column=snap,
+                     build_counts=(side.counts if side is not None
+                                   and side.version == col.version
+                                   else None))
         self.chains[col_id].versions.append(v)
         self.chains[col_id].dirty = False
         self.snapshots_created += 1
@@ -234,6 +305,11 @@ class ConsistencyManager:
     def read(self, handle: int, col_id: int) -> EncodedColumn:
         """Read the pinned version — O(1), no chain traversal (vs MVCC)."""
         return self._handles[handle][col_id].column
+
+    def build_counts(self, handle: int, col_id: int) -> np.ndarray | None:
+        """The pinned version's self-join build side (`BuildSide`), or
+        None where the column keeps none."""
+        return self._handles[handle][col_id].build_counts
 
     def read_scan(self, handle: int, col_id: int):
         """Pinned read for the scan plane: shard at pin, once per round.

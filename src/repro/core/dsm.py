@@ -104,6 +104,21 @@ def value_range_to_code_range(col: EncodedColumn, lo: int, hi: int):
     return code_lo, code_hi
 
 
+def new_values(old_dict: np.ndarray, update_dict: np.ndarray
+               ) -> tuple[np.ndarray, np.ndarray]:
+    """``(thresholds, values)``: the values of the sorted update
+    dictionary that the old one lacks, and where each lands among the old
+    codes, ``searchsorted(old_dict, u)``, both ascending. Every old value
+    survives a merge, so the merged dictionary is ``old_dict`` with the
+    new values inserted at their thresholds, and an old code ``c`` moves
+    to ``c + #{t : t <= c}``."""
+    pos = np.searchsorted(old_dict, update_dict)
+    known = np.zeros(len(pos), dtype=bool)
+    inside = pos < len(old_dict)
+    known[inside] = old_dict[pos[inside]] == update_dict[inside]
+    return pos[~known], update_dict[~known]
+
+
 # ---------------------------------------------------------------------------
 # Delta store: sorted per-column overlay of not-yet-compacted updates
 # ---------------------------------------------------------------------------

@@ -208,6 +208,7 @@ def resize_islands(session, n_islands: int,
     session.cons.rebind_backend(new_be)
     session.be = new_be
     session.islands = getattr(new_be, "n_shards", 1)
+    session._place_replica()
     hw = session.spec.hw
     if session.islands > 1 and hw.n_ana_islands == 1:
         hw = dataclasses.replace(hw, n_ana_islands=session.islands)
@@ -437,6 +438,7 @@ def restore_session(ckpt_dir: str, spec=None, step: int | None = None):
             dictionary=arrays[key + "dictionary"],
             valid=arrays[key + "valid"],
             version=versions[c])
+    session._place_replica()
 
     # delta overlays
     session._deltas = {}

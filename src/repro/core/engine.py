@@ -341,6 +341,7 @@ def run_query_group_dsm(
     n_shards: int | None = None,
     deltas: dict[int, ColumnDelta] | None = None,
     base_cols: dict[int, EncodedColumn] | None = None,
+    rcount: np.ndarray | None = None,
 ) -> list[int]:
     """Execute a same-column-set query group as one fused multi-query scan.
 
@@ -363,6 +364,10 @@ def run_query_group_dsm(
     columns to the base EncodedColumns the overlays are relative to (the
     pinned snapshot shares state with them — appends never dirty snapshot
     chains). Answers are bit-identical to eagerly applying the overlays.
+
+    ``rcount``, where the caller keeps it, is the pinned join column's
+    build side (`consistency.BuildSide`) for a read without overlays; the
+    backend counts it from the column otherwise.
     """
     if not queries:
         return []
@@ -410,7 +415,8 @@ def run_query_group_dsm(
             corr_rows += 2 * (nr_a + nr_j)
             corr_touched += nr_a + nr_j
         else:
-            fused_j = be.filter_agg_join_batch(fcol, acol, jcol_v, bounds)
+            fused_j = be.filter_agg_join_batch(fcol, acol, jcol_v, bounds,
+                                               rcount=rcount)
         for q, scj in zip(joins, fused_j):
             answers[id(q)] = scj
     out = []

@@ -40,16 +40,19 @@ import dataclasses
 import os
 from typing import Callable
 
+import jax
 import numpy as np
 
 from repro.core import engine
-from repro.core.application import (apply_updates, apply_updates_delta,
+from repro.core.application import (apply_updates_delta,
                                     apply_updates_naive,
-                                    apply_updates_shards, compaction_entries,
+                                    apply_updates_shards,
+                                    apply_updates_where, compaction_entries,
                                     delta_eligible, precompute_apply_stages)
-from repro.core.backend import ExecutionBackend, get_backend
+from repro.core.backend import ExecutionBackend, PallasBackend, get_backend
 from repro.core.consistency import ConsistencyManager
-from repro.core.dsm import ColumnDelta, DSMReplica, empty_delta
+from repro.core.dsm import (ColumnDelta, DSMReplica, EncodedColumn,
+                            empty_delta)
 from repro.core.hwmodel import (CostLog, HardwareParams, HB_PARAMS,
                                 HMC_PARAMS)
 from repro.core.mvcc import MVCCStore
@@ -369,6 +372,10 @@ class HTAPSession:
             self._deltas: dict[int, ColumnDelta] = {}  # col -> live overlay
             self.delta_appends = 0
             self.compactions = 0
+            # columns whose stage-3 re-encode ran on the device / the host
+            self.reencodes_device = 0
+            self.reencodes_host = 0
+            self._place_replica()
             # elastic island lifecycle (core/elastic.py): resize audit
             # trail + the crash-injection hook (REPRO_CRASH_AFTER arms it;
             # tests/harnesses may also set crash_after_ships directly)
@@ -394,6 +401,28 @@ class HTAPSession:
                 view = {c: self.be.shard_view(col)
                         for c, col in self.replica.columns.items()}
             self._view = view
+
+    def _place_replica(self) -> None:
+        """Put the replica on the device where its update plane applies
+        it there (MI family): the eager plane on a single-replica
+        accelerator backend, whose stage 3 then runs on the device
+        (`application.apply_updates_where`). The consistency manager then
+        keeps each column's self-join build side (`BuildSide`). The
+        placement is set-up: nothing on the hot path moves a whole column
+        across. Every other plane applies whatever it holds on the host,
+        and its joins count their build side from the column."""
+        if not (self.spec.optimized_application and not self.delta_enabled
+                and isinstance(self.be, PallasBackend)):
+            self.cons.build_sides.clear()
+            return
+        self.cons.keep_build_sides()
+        cols = self.replica.columns
+        for c, col in cols.items():
+            if not isinstance(col.codes, jax.Array):
+                cols[c] = EncodedColumn(
+                    codes=jax.device_put(col.codes),
+                    dictionary=col.dictionary,
+                    valid=jax.device_put(col.valid), version=col.version)
 
     # -- lifecycle ---------------------------------------------------------
     def _check_open(self) -> None:
@@ -475,8 +504,10 @@ class HTAPSession:
         """The session's work counts so far, flat, read without closing
         it: ``query_groups`` (query-group timeline nodes, every round),
         ``kernel_traces`` (jit traces of the kernel entry points), and on
-        the MI family ``ships`` and the application, compaction, snapshot
-        and view counts that `finish` reports."""
+        the MI family ``ships``, the application, compaction, snapshot
+        and view counts that `finish` reports, and ``reencodes_device`` /
+        ``reencodes_host``: the applied columns whose stage-3 re-encode
+        ran on the device / on the host."""
         from repro.kernels.common import kernel_trace_counts
         out = {"query_groups": sum(t.kind == "ana"
                                    for t in self.cost.tags.values()),
@@ -490,7 +521,9 @@ class HTAPSession:
                        snapshots_shared=cons.snapshots_shared,
                        views_built=cons.views_built,
                        views_shared=cons.views_shared,
-                       views_resident=cons.views_resident)
+                       views_resident=cons.views_resident,
+                       reencodes_device=self.reencodes_device,
+                       reencodes_host=self.reencodes_host)
         return out
 
     def abort(self) -> None:
@@ -678,6 +711,7 @@ class HTAPSession:
         with self.cost.tagged(node, kind, round=self.round, deps=deps,
                               col=col_id, islands=self.islands):
             mesh = getattr(self.be, "placement", "stacked") == "mesh"
+            where = "host"
             if spec.optimized_application and (self.islands > 1 or mesh):
                 # each island applies its own row range; the round
                 # becomes visible only as a complete shard set
@@ -688,16 +722,22 @@ class HTAPSession:
                     staged=staged_col, phase=phase)
                 swap = self.cons.on_update_shards
             elif spec.optimized_application:
-                new = apply_updates(
+                new, where = apply_updates_where(
                     old, entries, app_cost,
                     on_pim=spec.propagation_on_pim, backend=self.be,
                     staged=staged_col, phase=phase)
-                swap = self.cons.on_update
+
+                def swap(c, new):
+                    self.cons.on_update(c, new, updates=entries)
             else:
                 # the naive software baseline rebuilds a whole column
                 new = apply_updates_naive(old, entries, app_cost,
                                           phase=phase)
                 swap = self.cons.on_update
+            if where == "device":
+                self.reencodes_device += 1
+            else:
+                self.reencodes_host += 1
             with self.cost.span("swap"):
                 swap(col_id, new)
 
@@ -843,6 +883,7 @@ class HTAPSession:
                                   deps=snap_deps, islands=self.islands):
                 handles, view = self.cons.pin_scan_group(
                     [q.columns for q in group])
+            join_col = group[0].join_col
             with self.cost.tagged(f"r{self.round}:ana{g}", "ana",
                                   round=self.round, deps=(snap_node,),
                                   islands=self.islands, n=len(group)):
@@ -854,7 +895,9 @@ class HTAPSession:
                     on_pim=self.spec.analytics_on_pim, backend=self.be,
                     deltas=self._deltas if self.delta_enabled else None,
                     base_cols=(self.replica.columns
-                               if self.delta_enabled else None))
+                               if self.delta_enabled else None),
+                    rcount=(None if join_col is None else
+                            self.cons.build_counts(handles[0], join_col)))
             for q, a in zip(group, group_answers):
                 batch_results[id(q)] = a
             for h in handles:

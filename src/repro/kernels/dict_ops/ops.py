@@ -49,14 +49,30 @@ from repro.kernels.dict_ops.ref import (scan_filter_agg_batch_ref,
                                         scan_values_agg_ref)
 
 _I32_MAX = np.iinfo(np.int32).max
+# The widest padded dictionary XLA decodes by a compare/select chain: on a
+# v5e a 2^24-row decode takes about 3 ms at 32 or 64 entries, and XLA's
+# native gather takes 115-166 ms at every width from 128 to 2^17 (PERF.md,
+# section 7).
+DICT_SELECT_MAX = 64
+# The least width a wider dictionary operand is padded to. Every program
+# that decodes through a dictionary compiles once per padded width, a few
+# seconds each on the chip's host, so a column whose dictionary grows
+# through several powers of two inside a served window would compile its
+# scans each time; past `DICT_SELECT_MAX` it does so once more at most,
+# at 2^17 entries. The pad is a host array of 512 KiB, sent with each
+# scan.
+DICT_PAD_MIN = 1 << 17
 
 
 def pad_dictionary_pow2(dictionary):
-    """Pad a dictionary to the next power of two so growing dictionaries
-    reuse compiled shapes; padded entries are never addressed by a code.
-    Type-preserving: host numpy stays host numpy (no eager device op)."""
+    """Pad a dictionary to the next power of two up to `DICT_SELECT_MAX`
+    entries, and to at least `DICT_PAD_MIN` past it, so growing
+    dictionaries reuse compiled shapes; padded entries are never addressed
+    by a code. Type-preserving: host numpy stays host numpy (no eager
+    device op)."""
     k = dictionary.shape[0]
-    kpad = next_pow2(k) - k
+    w = next_pow2(k)
+    kpad = (w if w <= DICT_SELECT_MAX else max(w, DICT_PAD_MIN)) - k
     if not kpad:
         return dictionary
     if isinstance(dictionary, np.ndarray):

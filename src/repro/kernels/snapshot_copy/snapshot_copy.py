@@ -42,6 +42,20 @@ def snapshot_copy_lowered(src, prev, dirty, block: int = 8192):
     return out.reshape(-1)[:n]
 
 
+@functools.partial(instrumented_jit, static_argnames=("block",))
+def dirty_chunks(src, prev, block: int = 8192):
+    """The tracking buffer of two device-resident columns: an int32 flag
+    per ``block``-row chunk, 1 where the chunk differs. Computed where the
+    columns live, so neither comes back to the host."""
+    (n,) = src.shape
+    n_chunks = -(-n // block)
+    diff = src != prev
+    pad = n_chunks * block - n
+    if pad:
+        diff = jnp.pad(diff, (0, pad))
+    return diff.reshape(n_chunks, block).any(axis=1).astype(jnp.int32)
+
+
 @functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
 def snapshot_copy_kernel(src, prev, dirty, block: int = 8192,
                          interpret: bool = True):

@@ -382,3 +382,55 @@ def test_consistency_manager_pallas_snapshots(rng):
     h2 = cons.begin_query([0])
     assert int(np.asarray(decode_column(cons.read(h2, 0)))[5]) == 999_999
     cons.end_query(h2)
+
+
+@pytest.mark.parametrize("sizes", [(5000,), (5000, 300, 9000), (300, 4097)],
+                         ids=["one_large", "mixed", "small_and_large"])
+def test_apply_stages_batch_large_dictionaries_merge_by_insertion(rng,
+                                                                  sizes):
+    """Old dictionaries past the merge networks' reach merge by inserting
+    the new values at their thresholds, stage-for-stage equal to the
+    reference, whichever other columns share the batch."""
+    from repro.core.backend import MERGE_NETWORK_MAX_DICT
+
+    np_be, pl_be = get_backend("numpy"), get_backend("pallas")
+    per_column = []
+    for k in sizes:
+        o = np.sort(rng.choice(1 << 22, k, replace=False)).astype(np.int32)
+        wv = np.concatenate([rng.integers(0, 1 << 22, 100),
+                             rng.choice(o, 20)]).astype(np.int32)
+        per_column.append((o, wv))
+    assert any(k > MERGE_NETWORK_MAX_DICT for k in sizes)
+    got = pl_be.apply_stages_batch(per_column)
+    ref = np_be.apply_stages_batch(per_column)
+    for i, ((u_g, d_g, enc_g, m_g), (u_r, d_r, _, m_r)) in enumerate(
+            zip(got, ref)):
+        np.testing.assert_array_equal(u_g, u_r, err_msg=f"col {i} update")
+        np.testing.assert_array_equal(d_g, d_r, err_msg=f"col {i} merged")
+        np.testing.assert_array_equal(m_g, m_r, err_msg=f"col {i} remap")
+        np.testing.assert_array_equal(enc_g(per_column[i][1]),
+                                      np.searchsorted(d_r, per_column[i][1]))
+
+
+def test_snapshot_column_resident_tracks_dirty_chunks_on_device(rng):
+    """A device-resident column's snapshot computes its tracking buffer on
+    the device when the dictionaries match, and copies every chunk when
+    they differ; either way the snapshot equals the column."""
+    import jax
+
+    pl_be = get_backend("pallas")
+    host = _encoded(rng, 40_000, 63, invalid_frac=0.0)
+    col = type(host)(codes=jax.device_put(host.codes),
+                     dictionary=host.dictionary,
+                     valid=jax.device_put(np.asarray(host.valid)))
+    prev = pl_be.snapshot_column(col)
+    changed = np.asarray(host.codes).copy()
+    changed[[5, 20_000]] = (changed[[5, 20_000]] + 1) % 63
+    nxt = type(col)(codes=jax.device_put(changed), dictionary=col.dictionary,
+                    valid=col.valid, version=1)
+    with backend_mod.counting_kernel_calls() as counts:
+        for before in (prev, None):
+            snap = pl_be.snapshot_column(nxt, prev=before)
+            np.testing.assert_array_equal(np.asarray(snap.codes), changed)
+            assert snap.version == 1
+    assert counts == {"dirty_chunks": 1, "snapshot_copy": 2}

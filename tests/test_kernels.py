@@ -203,3 +203,29 @@ def test_flash_attention_matches_sdpa(rng):
         ref = _sdpa(q, k, v, m, kw.get("softcap", 0.0))
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("size", ["one", "select_max", "past_select",
+                                  "mid", "pad_min", "past_pad_min"])
+@pytest.mark.parametrize("on_device", [False, True], ids=["host", "device"])
+def test_dictionary_pad_widths(size, on_device):
+    """A scan's dictionary operand pads to the next power of two inside
+    XLA's compare/select range, and past it to at least `DICT_PAD_MIN`
+    entries; the values come first, the pad is zeros, host numpy stays
+    host numpy."""
+    from repro.kernels.common import next_pow2
+    from repro.kernels.dict_ops import ops
+
+    smax, pmin = ops.DICT_SELECT_MAX, ops.DICT_PAD_MIN
+    k = {"one": 1, "select_max": smax, "past_select": smax + 1,
+         "mid": 4097, "pad_min": pmin, "past_pad_min": pmin + 1}[size]
+    want = {"one": 1, "select_max": smax, "past_select": pmin,
+            "mid": pmin, "pad_min": pmin,
+            "past_pad_min": next_pow2(pmin + 1)}[size]
+    d = np.arange(1, k + 1, dtype=np.int32)
+    out = ops.pad_dictionary_pow2(jnp.asarray(d) if on_device else d)
+    assert isinstance(out, jax.Array if on_device else np.ndarray)
+    out = np.asarray(out)
+    assert out.shape == (want,)
+    np.testing.assert_array_equal(out[:k], d)
+    assert not out[k:].any()

@@ -324,3 +324,111 @@ def test_mesh_session_installs_mesh_for_its_lifetime(tiny_workload):
     assert current_island_mesh() is outer.be.mesh  # restored, not cleared
     outer.finish()
     assert current_island_mesh() is None
+
+
+# ---------------------------------------------------------------------------
+# Device-resident replica: the eager plane on one accelerator replica
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def ship_workload():
+    """Enough writes per round for capacity ships inside `execute` as well
+    as the flush before each query batch; half the queries self-join."""
+    rng = np.random.default_rng(5)
+    sch = schema.make_schema("t", 4, 32)
+    table = schema.gen_table(rng, sch, 3000)
+    stream = schema.gen_update_stream(rng, sch, 3000, 12_000,
+                                      write_ratio=0.5)
+    queries = engine.gen_queries(rng, 8, 4, join_fraction=0.5)
+    return table, stream, queries
+
+
+def _serve(spec, workload, rounds=4):
+    table, stream, queries = workload
+    session = HTAPSession(spec, table)
+    answers = []
+    for r, chunk in enumerate(split_stream(stream, rounds)):
+        if r:
+            session.advance_round()
+        for sub in _sub_chunks(chunk, [len(chunk) // 3]):
+            session.execute(sub)
+        answers.append(session.query_batch(queries))
+    return session, answers
+
+
+def test_resident_eager_session_matches_numpy(ship_workload):
+    """The eager plane on a single-replica pallas backend keeps its
+    replica on the device and re-encodes every applied column there; its
+    answers equal the numpy reference's across capacity ships and
+    flushes."""
+    import jax
+
+    spec = SystemSpec.polynesia(backend="pallas", n_shards=1,
+                                delta_store=False)
+    session, answers = _serve(spec, ship_workload)
+    _, want = _serve(spec.replace(backend="numpy"), ship_workload)
+    assert answers == want
+    counts = session.counters()
+    assert counts["ships"] > 4                      # capacity ships too
+    assert counts["reencodes_device"] == counts["applications"] > 0
+    assert counts["reencodes_host"] == 0
+    for c, col in session.replica.columns.items():
+        assert isinstance(col.codes, jax.Array)
+        assert isinstance(col.valid, jax.Array)
+        # the joins' build side followed every swap
+        codes, valid = np.asarray(col.codes), np.asarray(col.valid)
+        np.testing.assert_array_equal(
+            session.cons.build_sides[c].counts,
+            np.bincount(codes[valid], minlength=col.dict_size))
+
+
+@pytest.mark.parametrize("plane", [
+    dict(backend="pallas", n_shards=1, delta_store=True),
+    dict(backend="pallas", n_shards=2, delta_store=False)],
+    ids=["delta", "two_shards"])
+def test_host_planes_never_reencode_on_device(ship_workload, plane):
+    """The delta plane and the sharded islands keep their host base: no
+    column re-encodes on the device."""
+    session, _ = _serve(SystemSpec.polynesia(**plane), ship_workload,
+                        rounds=2)
+    counts = session.counters()
+    assert counts["reencodes_device"] == 0
+    # every column that did re-encode (a sharded apply, a compaction) did
+    # so on the host
+    assert counts["reencodes_host"] == (
+        counts["applications"] if plane["n_shards"] > 1
+        else counts["compactions"])
+    assert session.cons.build_sides == {}
+    if plane["delta_store"]:
+        for col in session.replica.columns.values():
+            assert isinstance(col.codes, np.ndarray)
+
+
+def test_pinned_snapshot_survives_device_apply(ship_workload):
+    """A snapshot pinned before an apply on the device still reads its own
+    version afterwards (the apply writes new device arrays), and its join
+    build side stays that version's."""
+    table, stream, queries = ship_workload
+    session = HTAPSession(SystemSpec.polynesia(
+        backend="pallas", n_shards=1, delta_store=False), table)
+    first, second = split_stream(stream, 2)
+    session.execute(first)
+    session.flush_updates()
+    c = int(np.bincount(second.col[second.op == 1]).argmax())
+    handle = session.cons.begin_query([c])
+    pinned = session.cons.read(handle, c)
+    codes, valid = np.array(pinned.codes), np.array(pinned.valid)
+    version = pinned.version
+    counts = session.cons.build_counts(handle, c).copy()
+    np.testing.assert_array_equal(
+        counts, np.bincount(codes[valid], minlength=pinned.dict_size))
+    session.execute(second)
+    session.flush_updates()
+    assert session.replica.columns[c].version > version
+    again = session.cons.read(handle, c)
+    assert again.version == version
+    np.testing.assert_array_equal(np.asarray(again.codes), codes)
+    np.testing.assert_array_equal(np.asarray(again.valid), valid)
+    np.testing.assert_array_equal(session.cons.build_counts(handle, c),
+                                  counts)
+    session.cons.end_query(handle)

@@ -94,9 +94,15 @@ def test_window_report_on_the_cpu(tool, process_state):
     assert out["correct"] is True
     assert set(out["metrics"]) == {
         "rowstore_ms_per_group", "ship_ms_per_ship", "reencode_ms_per_ship",
+        "reencodes_device", "reencodes_host", "reencode_device_share",
         "stages_ms_per_ship", "snapshot_ms_per_group", "scan_ms_per_group",
         "query_glue_ms_per_group", "retraces_in_window"}
     assert all(v >= 0 for v in out["metrics"].values())
+    # the eager plane's replica is on the device: every column of the
+    # window's ships re-encoded there
+    metrics = out["metrics"]
+    assert metrics["reencodes_device"] > 0 == metrics["reencodes_host"]
+    assert metrics["reencode_device_share"] == 100.0
     # the window's one batch is due at its start, when the warm-up has
     # left nothing to flush; its queries run inside snapshot and ana spans
     assert out["covers"]["flush"] == 0
@@ -121,3 +127,8 @@ def test_a_recorded_chip_trace_reduces_as_the_benchmark_does(tool, name):
     assert got["idle_s"] == pytest.approx(idle, rel=1e-9)
     assert got["idle_s_by_program_span"] == {
         tool.NO_SPAN: pytest.approx(idle, rel=1e-9)}
+    # each program's runs in the window, counted
+    calls = got["program_calls"]
+    assert sum(c for c, _ in calls.values()) == len(want["programs"])
+    for name, total in want["device_ops"]:
+        assert calls[name][1] == pytest.approx(total, rel=1e-9)

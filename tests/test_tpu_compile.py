@@ -30,12 +30,14 @@ from repro.kernels.dict_ops.dict_ops import (scan_filter_agg_exact_kernel,
 from repro.kernels.hash_probe import ops as hash_ops
 from repro.kernels.hash_probe.hash_probe import probe_table_sharded
 from repro.kernels.merge_runs.merge_runs import bitonic_merge_pair
+from repro.kernels.reencode.reencode import reencode_rows_kernel
 from repro.kernels.snapshot_copy.snapshot_copy import snapshot_copy_kernel
 
 ROWS = 1 << 24      # chip_smoke.py's table: 2^24 rows x 8 int32 columns
 ISLANDS = 4         # the four-chip mesh / stacked comparison
 Q = 8               # pow2-padded predicates of one query group
 DICT = 4096         # a column dictionary grown by ~3k update values
+SCAN_DICT = dict_ops.DICT_PAD_MIN  # a scan's padded dictionary operand
 CORR = 4096         # delta-overlay correction stack width
 BLOCK = 4096        # the scan entry points' default block
 SHIP_ROWS = 8       # one ship batch: a row per column
@@ -79,24 +81,26 @@ I32, BOOL = jnp.int32, jnp.bool_
 CASES = {
     "scan_exact": (
         scan_filter_agg_exact_kernel,
-        [((ROWS,), I32), ((ROWS,), I32), ((ROWS,), BOOL), ((DICT,), I32),
-         ((Q, 2), I32)],
+        [((ROWS,), I32), ((ROWS,), I32), ((ROWS,), BOOL),
+         ((SCAN_DICT,), I32), ((Q, 2), I32)],
         dict(block=BLOCK), 1),
     "scan_sharded": (
         scan_filter_agg_sharded_kernel,
         [((ISLANDS, ROWS // ISLANDS), I32)] * 2
-        + [((ISLANDS, ROWS // ISLANDS), BOOL), ((DICT,), I32), ((Q, 2), I32)],
+        + [((ISLANDS, ROWS // ISLANDS), BOOL), ((SCAN_DICT,), I32),
+           ((Q, 2), I32)],
         dict(block=BLOCK), 1),
     "scan_group": (
         dict_ops._scan_group_kernel,
-        [((ROWS,), I32), ((ROWS,), I32), ((ROWS,), BOOL), ((DICT,), I32),
-         ((Q, 2), I32), ((6, CORR), I32), ((Q, 2), I32)],
+        [((ROWS,), I32), ((ROWS,), I32), ((ROWS,), BOOL),
+         ((SCAN_DICT,), I32), ((Q, 2), I32), ((6, CORR), I32),
+         ((Q, 2), I32)],
         dict(block=BLOCK, cblock=BLOCK), 3),
     "scan_group_sharded": (
         dict_ops._scan_group_sharded_kernel,
         [((ISLANDS, ROWS // ISLANDS), I32)] * 2
-        + [((ISLANDS, ROWS // ISLANDS), BOOL), ((DICT,), I32), ((Q, 2), I32),
-           ((6, CORR), I32), ((Q, 2), I32)],
+        + [((ISLANDS, ROWS // ISLANDS), BOOL), ((SCAN_DICT,), I32),
+           ((Q, 2), I32), ((6, CORR), I32), ((Q, 2), I32)],
         dict(block=BLOCK, cblock=BLOCK), 3),
     "scan_values_delta": (
         dict_ops._scan_values_delta_kernel,
@@ -105,13 +109,13 @@ CASES = {
     "join_scan": (
         hash_ops._join_scan_pallas,
         [((ROWS,), I32)] * 3 + [((ROWS,), BOOL)] * 2
-        + [((DICT,), I32), ((DICT,), I32), ((Q, 2), I32)],
+        + [((SCAN_DICT,), I32), ((SCAN_DICT,), I32), ((Q, 2), I32)],
         dict(block=BLOCK), 2),
     "join_group": (
         hash_ops._join_group_pallas,
         [((ROWS,), I32)] * 3 + [((ROWS,), BOOL)] * 2
-        + [((DICT,), I32), ((DICT,), I32), ((Q, 2), I32), ((6, CORR), I32),
-           ((6, CORR), I32), ((Q, 2), I32)],
+        + [((SCAN_DICT,), I32), ((SCAN_DICT,), I32), ((Q, 2), I32),
+           ((6, CORR), I32), ((6, CORR), I32), ((Q, 2), I32)],
         dict(block=BLOCK, cblock_a=BLOCK, cblock_j=BLOCK), 6),
     "apply_pipeline": (
         dict_ops._apply_pipeline_kernel,
@@ -134,6 +138,14 @@ CASES = {
     "merge_pair": (
         bitonic_merge_pair, [((8, LOG), I32)] * 6, dict(block_rows=8), 1),
 }
+# the stage-3 re-encode at the threshold widths of one column's smallest
+# and largest warm-up flush
+for _w in (8, 256):
+    CASES[f"reencode_w{_w}"] = (
+        reencode_rows_kernel,
+        [((ROWS,), I32), ((ROWS,), BOOL), ((_w,), I32), ((1,), I32),
+         ((_w,), I32), ((_w,), I32), ((8,), I32)],
+        {}, 1)
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
@@ -157,13 +169,13 @@ def test_mesh_scan_compiles_one_island_per_device(topo, family):
         call = dict_ops._mesh_scan_call(mesh, BLOCK, "compiled")
         args = (_shapes(island, ((ISLANDS, width), I32),
                         ((ISLANDS, width), I32), ((ISLANDS, width), BOOL))
-                + _shapes(repl, ((DICT,), I32), ((Q, 2), I32)))
+                + _shapes(repl, ((SCAN_DICT,), I32), ((Q, 2), I32)))
         n_kernels = 1
     else:
         call = hash_ops._mesh_join_call(mesh, BLOCK, "compiled")
         args = (_shapes(island, *[((ISLANDS, width), I32)] * 3,
                         *[((ISLANDS, width), BOOL)] * 2)
-                + _shapes(repl, ((DICT,), I32), ((DICT,), I32),
+                + _shapes(repl, ((SCAN_DICT,), I32), ((SCAN_DICT,), I32),
                           ((Q, 2), I32)))
         n_kernels = 2
     text = call.lower(*args).compile().as_text()

@@ -13,8 +13,9 @@ program's spans and counters give over the window (``metrics``), how much
 of the benchmark's own ``flush`` and ``query_batch`` spans the program's
 spans cover, each span name's count and total and self seconds, the spans
 recorded a second and what one costs, the device idle time split by the
-innermost ``repro.*`` span open over it, and the longest idle gaps
-labelled by the innermost span of either kind.
+innermost ``repro.*`` span open over it, the longest idle gaps labelled by
+the innermost span of either kind, and each program's runs and seconds in
+the trace.
 
 The second form is one ``--trace 0`` run of ``chipbench.run`` with the
 recorder on from the session's start: set beside a plain ``--trace 0``
@@ -66,11 +67,14 @@ def span_totals(spans, t0: float, t1: float) -> dict:
     return out
 
 
-def layer_metrics(totals: dict, n_groups: int,
-                  traces: tuple[int, int]) -> dict:
+def layer_metrics(totals: dict, n_groups: int, traces: tuple[int, int],
+                  reencodes: tuple[int, int] = (0, 0)) -> dict:
     """The per-layer numbers of a window from its span totals
     (`span_totals`): milliseconds per commit group, per ship batch and per
-    query group, and the kernel traces the window added."""
+    query group, the kernel traces the window added, and, next to the
+    re-encode's milliseconds, the columns whose stage 3 the window ran on
+    the device and on the host (``reencodes``) with the device's share of
+    them in percent."""
 
     def count(name):
         return totals.get(name, [0, 0.0, 0.0])[0]
@@ -83,8 +87,12 @@ def layer_metrics(totals: dict, n_groups: int,
     if n_groups:
         out["rowstore_ms_per_group"] = ms("txn") / n_groups
     if ships:
+        device, host = reencodes
         out.update(ship_ms_per_ship=ms("ship_batch") / ships,
                    reencode_ms_per_ship=ms("reencode") / ships,
+                   reencodes_device=device, reencodes_host=host,
+                   reencode_device_share=(100.0 * device / (device + host)
+                                          if device + host else 0.0),
                    stages_ms_per_ship=ms("stages") / ships)
     if groups:
         out.update(snapshot_ms_per_group=ms("snapshot") / groups,
@@ -123,7 +131,9 @@ def reduce_trace(data) -> dict | None:
     open at its middle (a program span keeps its ``repro.`` prefix). Adds
     ``idle_s`` and ``idle_s_by_program_span``: the idle seconds of the
     first busy device under each innermost ``repro.*`` span (``NO_SPAN``
-    for none). None when no device ran anything."""
+    for none), and ``program_calls``: each program's runs in the traced
+    window and their seconds, ``[calls, seconds]``. None when no device
+    ran anything."""
     pd = data if hasattr(data, "planes") else trace.load(data)
     host = [(e.name, e.start_ns, e.end_ns) for plane in pd.planes
             if plane.name.startswith("/host:") for line in plane.lines
@@ -157,8 +167,13 @@ def reduce_trace(data) -> dict | None:
         for x, y in zip(cuts, cuts[1:]):
             name = innermost(program, (x + y) / 2) or NO_SPAN
             by_span[name] = by_span.get(name, 0) + (y - x) / 1e9
+    calls = {}
+    for name, seconds in out["programs"]:
+        c = calls.setdefault(name, [0, 0.0])
+        c[0] += 1
+        c[1] += seconds
     return {**out, "idle_s": sum(b - a for a, b in gaps) / 1e9,
-            "idle_s_by_program_span": by_span}
+            "idle_s_by_program_span": by_span, "program_calls": calls}
 
 
 def span_cost_s(n: int = 10000) -> float:
@@ -213,9 +228,16 @@ def report(workload: str, seed: int, seconds: float, *,
     t0, t1 = served.t0, served.t_close
     totals = span_totals(session.cost.spans, t0, t1)
     bench = [(n, a, b) for n, a, b in o.spans.spans if a >= t0 and b <= t1]
+
+    def added(name):
+        return (counters["close"].get(name, 0)
+                - counters["open"].get(name, 0))
+
     out = {"metrics": layer_metrics(totals, len(served.groups),
                                     (counters["open"]["kernel_traces"],
-                                     counters["close"]["kernel_traces"])),
+                                     counters["close"]["kernel_traces"]),
+                                    (added("reencodes_device"),
+                                     added("reencodes_host"))),
            "covers": coverage(bench, session.cost.spans),
            "spans": totals,
            "counters": counters,

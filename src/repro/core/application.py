@@ -35,8 +35,8 @@ import numpy as np
 
 from repro.core.backend import (PallasBackend, ShardedBackend, _fits_int32,
                                 get_backend)
-from repro.core.dsm import (ColumnDelta, EncodedColumn, new_values,
-                            shard_bounds)
+from repro.core.dsm import (ColumnDelta, EncodedColumn, ShardedView,
+                            host_column, new_values, shard_bounds)
 from repro.core.hwmodel import CostLog, span
 from repro.core.nsm import UPDATE_DTYPE
 from repro.core.schema import VALUE_BYTES
@@ -262,10 +262,14 @@ def apply_updates_where(
     backend=None,
     staged=None,
     phase: str = "apply",
-) -> tuple[EncodedColumn, str]:
+) -> tuple[EncodedColumn | ShardedView, str]:
     """`apply_updates`, and where its stage 3 ran: ``"device"`` or
-    ``"host"``."""
+    ``"host"``. A column whose islands live on their own devices (a
+    `ShardedView`) goes to `apply_updates_islands`."""
     be = get_backend(backend)
+    if isinstance(col, ShardedView):
+        return apply_updates_islands(col, updates, cost, on_pim, be, staged,
+                                     phase)
     if isinstance(be, ShardedBackend) and be.n_shards > 1:
         from repro.core.dsm import concat_columns
         return concat_columns(apply_updates_shards(col, updates, cost,
@@ -391,10 +395,10 @@ def apply_updates_shards(
     write_codes = inner.encode_values_shards(
         encode, [w["value"] for *_, w in island_ops])
     codes_parts, valid_parts = [], []
-    with span("reencode", n=n):
-        for s, ((m_s, i_s, d_s, w_s), wc) in enumerate(zip(island_ops,
-                                                           write_codes)):
-            lo, hi = bounds[s], bounds[s + 1]
+    for s, ((m_s, i_s, d_s, w_s), wc) in enumerate(zip(island_ops,
+                                                       write_codes)):
+        lo, hi = bounds[s], bounds[s + 1]
+        with span("reencode", n=hi - lo):
             src_lo, src_hi = min(lo, n), min(hi, n)
             codes_s = old_to_new[old_codes[src_lo:src_hi]].astype(np.int32)
             valid_s = np.array(old_valid[src_lo:src_hi], copy=True)
@@ -405,8 +409,8 @@ def apply_updates_shards(
             codes_s, valid_s = _apply_row_ops(codes_s, valid_s, new_dict,
                                               m_s, i_s, d_s, encode=encode,
                                               write_set=(w_s, wc))
-            codes_parts.append(codes_s)
-            valid_parts.append(valid_s)
+        codes_parts.append(codes_s)
+        valid_parts.append(valid_s)
 
     if cost is not None and m:
         _optimized_apply_cost(cost, on_pim, m, n, k_old, len(new_dict),
@@ -418,6 +422,63 @@ def apply_updates_shards(
                       valid=np.asarray(valid_s), version=col.version + 1)
         for codes_s, valid_s in zip(codes_parts, valid_parts)
     ]
+
+
+def apply_updates_islands(
+    view: ShardedView,
+    updates: np.ndarray,
+    cost: CostLog | None = None,
+    on_pim: bool = True,
+    backend=None,
+    staged=None,
+    phase: str = "apply",
+) -> tuple[ShardedView, str]:
+    """Update application on islands that each hold their shard on their
+    own device (a `MeshBackend`'s eager plane): the new resident column,
+    and where its stage 3 ran.
+
+    Stages 1-2 run once on the host, as the dictionary is replicated,
+    giving one threshold vector. Each update goes to its island by row
+    (`route_updates`), in local row ids, and one launch runs every
+    island's stage 3 on its device (`MeshBackend.reencode_islands`).
+    Nothing crosses to the host. Each island's host prep is a
+    ``reencode`` span of its rows; the launch leaves in the last one. An
+    insert batch, or values past int32, takes the host stage 3
+    (`apply_updates_shards`) as on one chip, and its shards go back to
+    their devices. Either way the result is the same rows as the
+    unsharded `apply_updates`.
+    """
+    be = get_backend(backend)
+    mods, ins, dels = _split_ops(updates)
+    if len(ins) or not _fits_int32(mods["value"]):
+        shards = apply_updates_shards(host_column(view), updates, cost,
+                                      on_pim, be, staged=staged, phase=phase)
+        return be.place_shards(shards), "host"
+    old_dict = np.asarray(view.dictionary)
+    update_dict, new_dict, encode, _ = (
+        staged if staged is not None
+        else _merge_dictionary_stages(be.inner, old_dict, mods["value"]))
+    thresholds, _ = new_values(old_dict, update_dict)
+    owner = route_updates(updates, list(view.bounds))
+    writes, deletes = [], []
+    for s in range(view.n_shards):
+        with span("reencode", n=view.sizes[s]):
+            ups = updates[owner == s]
+            ups["row"] -= view.bounds[s]      # island-local row ids
+            m_s, _, d_s = _split_ops(ups)
+            w_s = _sorted_write_ops(m_s, ins)
+            writes.append(_last_writes(w_s, encode(w_s["value"])))
+            deletes.append(d_s["row"])
+            if s == view.n_shards - 1:
+                codes, valid = be.reencode_islands(view, thresholds, writes,
+                                                   deletes)
+    if cost is not None and len(updates):
+        _optimized_apply_cost(cost, on_pim, len(updates), view.n_rows,
+                              len(old_dict), len(new_dict), len(update_dict),
+                              view.bit_width, phase=phase)
+    return ShardedView(codes=codes, valid=valid,
+                       dictionary=np.asarray(new_dict), bounds=view.bounds,
+                       version=view.version + 1), "device"
 
 
 def apply_updates_naive(

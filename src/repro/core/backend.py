@@ -76,8 +76,9 @@ from repro.kernels.hash_probe import (EMPTY_KEY, build_table, probe,
                                       scan_filter_agg_join_mesh,
                                       scan_filter_agg_join_sharded)
 from repro.kernels.merge_runs import merge_sorted_pairs, merge_sorted_runs
-from repro.kernels.reencode import reencode_rows
-from repro.kernels.snapshot_copy import dirty_chunks, snapshot_copy
+from repro.kernels.reencode import reencode_rows, reencode_rows_mesh
+from repro.kernels.snapshot_copy import (dirty_chunks, snapshot_copy,
+                                         snapshot_copy_mesh)
 
 SNAPSHOT_BLOCK = 8192  # copy-unit chunk size (kernels/snapshot_copy default)
 # The largest old dictionary the device merge networks take. A merge
@@ -104,7 +105,8 @@ KERNEL_ENTRY_POINTS = ("scan_filter_agg", "scan_filter_agg_batch",
                        "merge_sorted_pairs", "sort_1024", "sort_rows",
                        "snapshot_copy", "scan_values_agg",
                        "scan_values_delta", "apply_pipeline_batch",
-                       "reencode_rows", "dirty_chunks")
+                       "reencode_rows", "reencode_rows_mesh",
+                       "snapshot_copy_mesh", "dirty_chunks")
 # The entry points among them that scan columns for a query group: each
 # call is a ``scan`` span of the recording CostLog (`_scan_span`).
 SCAN_ENTRY_POINTS = ("scan_filter_agg", "scan_filter_agg_batch",
@@ -1194,9 +1196,13 @@ class MeshBackend(ShardedBackend):
     (`kernels.dict_ops.scan_filter_agg_mesh` /
     `kernels.hash_probe.scan_filter_agg_join_mesh`) — replacing the host
     `reduce_partials` loop while staying bit-identical to it (16-bit
-    psum lanes, recombined exactly on the host). Everything off the scan
-    plane (update propagation, snapshots, dictionary stages) is
-    host-side control-plane work and delegates unchanged.
+    psum lanes, recombined exactly on the host).
+
+    On the eager plane the replica's columns are themselves resident
+    views (`HTAPSession._place_replica`): stage 3 runs on every island's
+    device in one launch (`reencode_islands`), and snapshots copy each
+    shard on its device (`snapshot_column`). The dictionary stages run
+    once on the host, as the dictionary is replicated.
 
     Requires `n_shards` devices; `distributed.island_mesh` raises an
     actionable error (naming the ``--xla_force_host_platform_device_count``
@@ -1238,6 +1244,38 @@ class MeshBackend(ShardedBackend):
         with no concat + re-split round trip through the host."""
         return self._place_view(
             stack_shard_columns(shard_cols, snapshot_id=snapshot_id))
+
+    # -- islands resident on their devices: stage 3 and snapshots there ---
+    def reencode_islands(self, view: ShardedView, thresholds, writes,
+                         del_rows):
+        """Stage 3 of the optimized apply on every island's device, in one
+        launch: the compare-and-add re-encode of each resident shard
+        against the replicated ``thresholds``, then that island's row ops
+        (``writes``: its ``(rows, codes)`` last writes; ``del_rows``: its
+        deletes; both in local row ids). Returns the new stacked
+        ``(codes, valid)``, each shard on its island's device; the inputs
+        stay valid, so pinned snapshots that alias them keep their
+        version."""
+        return reencode_rows_mesh(view.codes, view.valid, thresholds,
+                                  writes, del_rows, self.mesh)
+
+    def snapshot_column(self, col, prev=None):
+        """A resident island column (a `ShardedView`) is snapshotted on
+        each island's device (`snapshot_copy_mesh`), carrying the chunks
+        that match ``prev``'s shards under the same dictionary; a host
+        column goes through the inner backend's copy unit."""
+        if not isinstance(col, ShardedView):
+            return super().snapshot_column(col, prev=prev)
+        carry = (isinstance(prev, ShardedView) and prev.bounds == col.bounds
+                 and (prev.dictionary is col.dictionary
+                      or np.array_equal(np.asarray(prev.dictionary),
+                                        np.asarray(col.dictionary))))
+        codes = snapshot_copy_mesh(col.codes,
+                                   prev.codes if carry else col.codes,
+                                   self.mesh, block=SNAPSHOT_BLOCK)
+        return ShardedView(codes=codes, valid=col.valid,
+                           dictionary=col.dictionary, bounds=col.bounds,
+                           version=col.version)
 
     # -- analytical engine: one shard_map launch, psum reduction -----------
     def filter_agg_batch(self, fcol, acol, bounds):

@@ -28,7 +28,7 @@ import numpy as np
 from repro.core.application import _apply_row_ops, _split_ops
 from repro.core.backend import get_backend
 from repro.core.dsm import (DSMReplica, EncodedColumn, ShardedView,
-                            concat_columns, new_values)
+                            concat_columns, host_column, new_values)
 from repro.core.hwmodel import CostLog
 from repro.core.schema import VALUE_BYTES
 
@@ -46,8 +46,9 @@ class BuildSide:
     own, so a snapshot keeps the counts of the version it pinned.
     """
 
-    def __init__(self, col: EncodedColumn):
-        codes = np.asarray(col.codes)
+    def __init__(self, col):
+        col = host_column(col)
+        codes = col.codes
         self.dictionary = np.asarray(col.dictionary)
         self.values = self.dictionary[codes]
         self.valid = np.array(col.valid, dtype=bool)
@@ -320,8 +321,20 @@ class ConsistencyManager:
         snapshot version — so a round shards each column exactly once, and
         all islands scan their resident shards in one batched launch. On
         single-replica backends it is `read` (the plain pinned column).
+        A column whose islands live on their own devices snapshots as a
+        `ShardedView` already: its first pinned read adopts it.
         """
         v = self._handles[handle][col_id]
+        if isinstance(v.column, ShardedView):
+            # a resident island column: the pinned snapshot's own shards,
+            # copied on their devices, are the view (never a host shard)
+            if v.view is None or v.view.stale:
+                v.view = dataclasses.replace(v.column,
+                                             snapshot_id=v.version_id)
+                self.views_resident += 1
+            else:
+                self.views_shared += 1
+            return v.view
         if (getattr(self.backend, "n_shards", 1) <= 1
                 and getattr(self.backend, "placement", "stacked") != "mesh"):
             return v.column

@@ -419,9 +419,31 @@ def stack_shard_columns(shard_cols: list[EncodedColumn],
                        version=head.version, snapshot_id=snapshot_id)
 
 
+def host_column(col) -> EncodedColumn:
+    """``col`` as an EncodedColumn of host numpy arrays, fetched on demand.
+
+    A replica column whose islands live on their own devices is a
+    `ShardedView`; this reassembles its shards in row order. Checkpoints,
+    resizes and the join build side read host codes through it."""
+    if isinstance(col, ShardedView):
+        codes, valid = np.asarray(col.codes), np.asarray(col.valid)
+        return EncodedColumn(
+            codes=np.concatenate([codes[s, :n]
+                                  for s, n in enumerate(col.sizes)]),
+            dictionary=np.asarray(col.dictionary),
+            valid=np.concatenate([valid[s, :n]
+                                  for s, n in enumerate(col.sizes)]),
+            version=col.version)
+    return EncodedColumn(codes=np.asarray(col.codes),
+                         dictionary=np.asarray(col.dictionary),
+                         valid=np.asarray(col.valid), version=col.version)
+
+
 @dataclasses.dataclass
 class DSMReplica:
-    """The analytical island's replica: one EncodedColumn per table column."""
+    """The analytical island's replica: one EncodedColumn per table column,
+    or, where each island's shard lives on its own device, one resident
+    `ShardedView` (`host_column` fetches its codes)."""
 
     columns: dict[int, EncodedColumn]
 

@@ -214,32 +214,20 @@ def resize_islands(session, n_islands: int,
         hw = dataclasses.replace(hw, n_ana_islands=session.islands)
     session.hw = hw
 
-    # 5. mesh context: install the resized device mesh (keeping the
-    #    pre-session mesh for finish()/abort() to restore), or release the
+    # 5. mesh context: install the resized device mesh, or release the
     #    old one when resizing away from mesh placement
-    was_mesh = session._installed_mesh
+    from repro.distributed import install_island_mesh, release_island_mesh
+    release_island_mesh(session)
     if getattr(new_be, "placement", "stacked") == "mesh":
-        from repro.distributed import (current_island_mesh,
-                                       install_island_mesh)
-        if not was_mesh:
-            session._prev_mesh = current_island_mesh()
-        install_island_mesh(new_be.mesh)
-        session._installed_mesh = True
-        # re-place the repartitioned shards on the resized device set NOW
-        # (Phase-2 residency handoff): the next pinned read adopts
-        # device-resident islands instead of re-sharding through the host
+        install_island_mesh(new_be.mesh, owner=session)
+        # re-place the repartitioned shards of host columns on the resized
+        # device set NOW (Phase-2 residency handoff): the next pinned read
+        # adopts device-resident islands instead of re-sharding through
+        # the host. Resident columns were re-placed with the replica.
         for col_id, col in session.replica.columns.items():
-            session.cons._resident[col_id] = new_be.place_shards(
-                dsm.shard_column(col, session.islands))
-    elif was_mesh:
-        from repro.distributed import (clear_island_mesh,
-                                       install_island_mesh)
-        if session._prev_mesh is not None:
-            install_island_mesh(session._prev_mesh)
-        else:
-            clear_island_mesh()
-        session._installed_mesh = False
-        session._prev_mesh = None
+            if not isinstance(col, dsm.ShardedView):
+                session.cons._resident[col_id] = new_be.place_shards(
+                    dsm.shard_column(col, session.islands))
 
     # 6. visibility: every column's next pin waits for the rebalance
     for col_id in session.replica.columns:
@@ -317,9 +305,10 @@ def checkpoint_session(session, ckpt_dir: str, step: int | None = None) -> int:
                 pending[field])
     col_versions = {}
     for c, col in session.replica.columns.items():
-        tree[f"col{c}/codes"] = np.asarray(col.codes)
-        tree[f"col{c}/dictionary"] = np.asarray(col.dictionary)
-        tree[f"col{c}/valid"] = np.asarray(col.valid)
+        col = dsm.host_column(col)   # resident islands come back here
+        tree[f"col{c}/codes"] = col.codes
+        tree[f"col{c}/dictionary"] = col.dictionary
+        tree[f"col{c}/valid"] = col.valid
         col_versions[c] = int(col.version)
     delta_meta = {}
     for c, d in session._deltas.items():

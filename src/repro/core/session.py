@@ -49,10 +49,11 @@ from repro.core.application import (apply_updates_delta,
                                     apply_updates_shards,
                                     apply_updates_where, compaction_entries,
                                     delta_eligible, precompute_apply_stages)
-from repro.core.backend import ExecutionBackend, PallasBackend, get_backend
+from repro.core.backend import (ExecutionBackend, MeshBackend,
+                                PallasBackend, get_backend)
 from repro.core.consistency import ConsistencyManager
 from repro.core.dsm import (ColumnDelta, DSMReplica, EncodedColumn,
-                            empty_delta)
+                            ShardedView, empty_delta, host_column)
 from repro.core.hwmodel import (CostLog, HardwareParams, HB_PARAMS,
                                 HMC_PARAMS)
 from repro.core.mvcc import MVCCStore
@@ -308,7 +309,8 @@ class HTAPSession:
     (concurrent-query staleness, §3.1), Ana-Only reads the initial table.
     """
 
-    def __init__(self, spec: SystemSpec, table: np.ndarray):
+    def __init__(self, spec: SystemSpec, table: np.ndarray,
+                 record_spans: bool = False):
         self.spec = spec
         # start from a clean jit-trace ledger so finish()'s
         # stats["traces"] covers exactly THIS session's lifetime (ad-hoc
@@ -321,6 +323,8 @@ class HTAPSession:
                 "async_propagation requires timing='timeline' (the "
                 "phase-bucket model has no round boundaries to overlap)")
         self.cost = CostLog()
+        if record_spans:   # set-up included (`CostLog.record_spans`)
+            self.cost.record_spans()
         self.round = 0
         self.results: list[int] = []
         self.n_txn = 0
@@ -343,19 +347,14 @@ class HTAPSession:
                                   placement=spec.placement)
         self.hw = hw
         self.islands = getattr(self.be, "n_shards", 1)
-        self._installed_mesh = False
-        self._prev_mesh = None
         if getattr(self.be, "placement", "stacked") == "mesh":
-            # make the islands' device mesh the process-global context, so
-            # ad-hoc get_backend("...@N/mesh") calls elsewhere in the
-            # process resolve onto the same devices; finish() restores the
-            # previous context, so a later session in the same process with
-            # a different island count never sees this session's stale mesh
-            from repro.distributed import (current_island_mesh,
-                                           install_island_mesh)
-            self._prev_mesh = current_island_mesh()
-            install_island_mesh(self.be.mesh)
-            self._installed_mesh = True
+            # make the islands' device mesh the process-global context for
+            # the session's lifetime, so ad-hoc get_backend("...@N/mesh")
+            # calls elsewhere in the process resolve onto the same devices;
+            # finish() releases it (so does dropping the session), so a
+            # later session with a different island count never sees it
+            from repro.distributed import install_island_mesh
+            install_island_mesh(self.be.mesh, owner=self)
         if kind == "multi_instance":
             self.store = RowStore(table)
             self.replica = DSMReplica.from_table(table)
@@ -372,7 +371,7 @@ class HTAPSession:
             self._deltas: dict[int, ColumnDelta] = {}  # col -> live overlay
             self.delta_appends = 0
             self.compactions = 0
-            # columns whose stage-3 re-encode ran on the device / the host
+            # column shards whose stage-3 re-encode ran on the device / host
             self.reencodes_device = 0
             self.reencodes_host = 0
             self._place_replica()
@@ -403,26 +402,43 @@ class HTAPSession:
             self._view = view
 
     def _place_replica(self) -> None:
-        """Put the replica on the device where its update plane applies
-        it there (MI family): the eager plane on a single-replica
-        accelerator backend, whose stage 3 then runs on the device
-        (`application.apply_updates_where`). The consistency manager then
-        keeps each column's self-join build side (`BuildSide`). The
-        placement is set-up: nothing on the hot path moves a whole column
-        across. Every other plane applies whatever it holds on the host,
-        and its joins count their build side from the column."""
+        """Put the replica where its update plane applies it (MI family),
+        once, as set-up (also after an `elastic` resize or restore):
+        nothing on the hot path moves a whole column across.
+
+        On the eager plane of an accelerator backend the columns live on
+        the device: one stacked `pallas` replica keeps its codes and
+        validity there, and islands on a device mesh (`MeshBackend`) each
+        hold their shard on their own device, the column then being the
+        resident `ShardedView` that the first pinned read adopts. Stage 3
+        runs where the column lives (`application.apply_updates_where`),
+        and the consistency manager keeps each column's self-join build
+        side (`BuildSide`). Every other plane applies host columns, and
+        its joins count their build side from the column."""
+        be, cols = self.be, self.replica.columns
         if not (self.spec.optimized_application and not self.delta_enabled
-                and isinstance(self.be, PallasBackend)):
+                and isinstance(be, (PallasBackend, MeshBackend))):
             self.cons.build_sides.clear()
+            for c, col in cols.items():
+                if isinstance(col, ShardedView):
+                    cols[c] = host_column(col)
             return
         self.cons.keep_build_sides()
-        cols = self.replica.columns
-        for c, col in cols.items():
-            if not isinstance(col.codes, jax.Array):
-                cols[c] = EncodedColumn(
-                    codes=jax.device_put(col.codes),
-                    dictionary=col.dictionary,
-                    valid=jax.device_put(col.valid), version=col.version)
+        with self.cost.span("place",
+                            n=sum(col.n_rows for col in cols.values())):
+            for c, col in cols.items():
+                if isinstance(be, MeshBackend):
+                    # sharded on the host here, and never again
+                    cols[c] = be.shard_view(host_column(col))
+                    continue
+                if isinstance(col, ShardedView):
+                    col = host_column(col)
+                if not isinstance(col.codes, jax.Array):
+                    col = EncodedColumn(
+                        codes=jax.device_put(col.codes),
+                        dictionary=col.dictionary,
+                        valid=jax.device_put(col.valid), version=col.version)
+                cols[c] = col
 
     # -- lifecycle ---------------------------------------------------------
     def _check_open(self) -> None:
@@ -454,17 +470,10 @@ class HTAPSession:
         """Price the accumulated cost log -> RunResult (closes the session)."""
         self._check_open()
         self._finished = True
-        if self._installed_mesh:
-            # release the process-global mesh context installed in
-            # __init__: restore whatever was there before (another live
-            # session's mesh) or clear it, so a later session with a
-            # different island count resolves fresh devices
-            from repro.distributed import (clear_island_mesh,
-                                           install_island_mesh)
-            if self._prev_mesh is not None:
-                install_island_mesh(self._prev_mesh)
-            else:
-                clear_island_mesh()
+        # release the process-global mesh context installed in __init__:
+        # the mesh installed before it (another live session's) is back
+        from repro.distributed import release_island_mesh
+        release_island_mesh(self)
         from repro.core import htap
         spec = self.spec
         stats: dict = {}
@@ -506,8 +515,9 @@ class HTAPSession:
         ``kernel_traces`` (jit traces of the kernel entry points), and on
         the MI family ``ships``, the application, compaction, snapshot
         and view counts that `finish` reports, and ``reencodes_device`` /
-        ``reencodes_host``: the applied columns whose stage-3 re-encode
-        ran on the device / on the host."""
+        ``reencodes_host``: the column shards (one per island, one for an
+        unsharded column) whose stage-3 re-encode ran on the device / on
+        the host."""
         from repro.kernels.common import kernel_trace_counts
         out = {"query_groups": sum(t.kind == "ana"
                                    for t in self.cost.tags.values()),
@@ -535,13 +545,8 @@ class HTAPSession:
         if self._finished:
             return
         self._finished = True
-        if self._installed_mesh:
-            from repro.distributed import (clear_island_mesh,
-                                           install_island_mesh)
-            if self._prev_mesh is not None:
-                install_island_mesh(self._prev_mesh)
-            else:
-                clear_island_mesh()
+        from repro.distributed import release_island_mesh
+        release_island_mesh(self)
         from repro.kernels.common import reset_kernel_trace_counts
         reset_kernel_trace_counts()
 
@@ -711,21 +716,25 @@ class HTAPSession:
         with self.cost.tagged(node, kind, round=self.round, deps=deps,
                               col=col_id, islands=self.islands):
             mesh = getattr(self.be, "placement", "stacked") == "mesh"
-            where = "host"
-            if spec.optimized_application and (self.islands > 1 or mesh):
-                # each island applies its own row range; the round
-                # becomes visible only as a complete shard set
+            where, shards = "host", 1
+            if (spec.optimized_application and (self.islands > 1 or mesh)
+                    and not isinstance(old, ShardedView)):
+                # each island applies its own row range of a host column;
+                # the round becomes visible only as a complete shard set
                 # (all-or-none Phase-2 swap)
                 new = apply_updates_shards(
                     old, entries, app_cost,
                     on_pim=spec.propagation_on_pim, backend=self.be,
                     staged=staged_col, phase=phase)
-                swap = self.cons.on_update_shards
+                swap, shards = self.cons.on_update_shards, len(new)
             elif spec.optimized_application:
+                # one column, or islands resident on their devices, whose
+                # new shards install as one view
                 new, where = apply_updates_where(
                     old, entries, app_cost,
                     on_pim=spec.propagation_on_pim, backend=self.be,
                     staged=staged_col, phase=phase)
+                shards = getattr(new, "n_shards", 1)
 
                 def swap(c, new):
                     self.cons.on_update(c, new, updates=entries)
@@ -735,9 +744,9 @@ class HTAPSession:
                                           phase=phase)
                 swap = self.cons.on_update
             if where == "device":
-                self.reencodes_device += 1
+                self.reencodes_device += shards
             else:
-                self.reencodes_host += 1
+                self.reencodes_host += shards
             with self.cost.span("swap"):
                 swap(col_id, new)
 

@@ -24,6 +24,7 @@ when a partitioning context is installed.
 from __future__ import annotations
 
 import contextlib
+import weakref
 
 import jax
 
@@ -33,12 +34,18 @@ from repro.distributed.sharding import ISLAND_AXIS
 # Island mesh (HTAP plane)
 # ---------------------------------------------------------------------------
 
-_ISLAND_MESH = None                 # installed by HTAPSession
+# Installed island meshes, oldest first: (owner, mesh), the owner a weak
+# reference to the session that installed it, or None for an install
+# that only `clear_island_mesh` removes. The newest entry whose owner is
+# alive is the process's mesh, so a session that is dropped unfinished
+# never leaves its mesh behind.
+_installed: list[tuple[weakref.ref | None, object]] = []
 _mesh_cache: dict[int, object] = {}  # built meshes by island count
 
 
-def install_island_mesh(mesh) -> None:
-    """Install `mesh` as the process's island mesh (HTAPSession does this).
+def install_island_mesh(mesh, owner=None) -> None:
+    """Install `mesh` as the process's island mesh (HTAPSession does this,
+    as its ``owner``, for its lifetime: `release_island_mesh`).
 
     The mesh must carry exactly the ``ISLAND_AXIS`` axis — installing an
     arbitrary LM-style mesh here would silently misplace shard arrays.
@@ -47,18 +54,25 @@ def install_island_mesh(mesh) -> None:
         raise ValueError(
             f"island mesh must have exactly one axis {ISLAND_AXIS!r}, got "
             f"axes {tuple(mesh.axis_names)}")
-    global _ISLAND_MESH
-    _ISLAND_MESH = mesh
+    _installed.append((None if owner is None else weakref.ref(owner), mesh))
+
+
+def release_island_mesh(owner) -> None:
+    """Remove every mesh ``owner`` installed; the one installed before it,
+    by an owner still alive, is the process's mesh again."""
+    _installed[:] = [(ref, m) for ref, m in _installed
+                     if ref is None or ref() is not owner]
 
 
 def current_island_mesh():
     """The installed island mesh, or None."""
-    return _ISLAND_MESH
+    _installed[:] = [(ref, m) for ref, m in _installed
+                     if ref is None or ref() is not None]
+    return _installed[-1][1] if _installed else None
 
 
 def clear_island_mesh() -> None:
-    global _ISLAND_MESH
-    _ISLAND_MESH = None
+    _installed.clear()
 
 
 def island_mesh(n_islands: int):
@@ -72,9 +86,9 @@ def island_mesh(n_islands: int):
     n_islands = int(n_islands)
     if n_islands < 1:
         raise ValueError(f"n_islands must be >= 1, got {n_islands}")
-    if (_ISLAND_MESH is not None
-            and _ISLAND_MESH.shape[ISLAND_AXIS] == n_islands):
-        return _ISLAND_MESH
+    installed = current_island_mesh()
+    if installed is not None and installed.shape[ISLAND_AXIS] == n_islands:
+        return installed
     mesh = _mesh_cache.get(n_islands)
     if mesh is None:
         have = jax.device_count()

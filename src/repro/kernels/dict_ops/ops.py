@@ -500,7 +500,7 @@ def _mesh_scan_call(mesh, block: int, mode: str):
     16-bit lanes (see `common.psum_split16`), so the launch's outputs are
     already cross-island totals — O(1) host work regardless of islands.
     """
-    def body(fcodes, acodes, valid, dictionary, bounds):
+    def scan_exact_mesh(fcodes, acodes, valid, dictionary, bounds):
         if mode == "lowered":
             fc, ac, v = pad_rows_sharded(fcodes, acodes, valid, block)
             parts = scan_exact_sharded_partials(fc, ac, v, dictionary,
@@ -515,7 +515,7 @@ def _mesh_scan_call(mesh, block: int, mode: str):
         return tuple(out)
 
     smapped = jax.shard_map(
-        body, mesh=mesh,
+        scan_exact_mesh, mesh=mesh,
         in_specs=(island_spec(), island_spec(), island_spec(),
                   replicated_spec(), replicated_spec()),
         out_specs=(P(None, None),) * 8,

@@ -426,7 +426,8 @@ def _mesh_join_call(mesh, block: int, mode: str):
     (1, width) shard, and all eight split-accumulator components come back
     psum'd over ``ISLAND_AXIS`` as 16-bit lane pairs (exact — see
     `common.psum_split16`)."""
-    def body(fcodes, acodes, jcodes, fvalid, jvalid, adict, rcount, bounds):
+    def scan_exact_join_mesh(fcodes, acodes, jcodes, fvalid, jvalid, adict,
+                             rcount, bounds):
         fc, ac, jc, fv, jv = _pad_join_width(
             fcodes, acodes, jcodes, fvalid, jvalid, block)
         if mode == "lowered":
@@ -447,7 +448,7 @@ def _mesh_join_call(mesh, block: int, mode: str):
         return tuple(out)
 
     smapped = jax.shard_map(
-        body, mesh=mesh,
+        scan_exact_join_mesh, mesh=mesh,
         in_specs=(island_spec(),) * 5 + (replicated_spec(),) * 3,
         out_specs=(P(None, None),) * 16,
         check_vma=False)  # pallas_call has no replication rule

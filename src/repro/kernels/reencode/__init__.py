@@ -1,1 +1,1 @@
-from repro.kernels.reencode.ops import reencode_rows
+from repro.kernels.reencode.ops import reencode_rows, reencode_rows_mesh

@@ -2,7 +2,6 @@
 own spans, and the device idle time split by them."""
 
 import importlib.util
-import os
 import types
 from pathlib import Path
 
@@ -64,26 +63,6 @@ def test_a_trace_without_device_work_gives_nothing(tool):
         is None
 
 
-@pytest.fixture
-def process_state(monkeypatch):
-    """Give back what opening a cell changes for the whole process: it
-    drops every ``REPRO_*`` variable and turns JAX's persistent compile
-    cache on for every compile."""
-    import jax
-    from jax._src import compilation_cache
-
-    for k in [k for k in os.environ if k.startswith("REPRO_")]:
-        monkeypatch.setenv(k, os.environ[k])   # put back at teardown
-    names = ("jax_compilation_cache_dir",
-             "jax_persistent_cache_min_compile_time_secs",
-             "jax_persistent_cache_min_entry_size_bytes")
-    before = {n: getattr(jax.config, n) for n in names}
-    yield
-    for n, v in before.items():
-        jax.config.update(n, v)
-    compilation_cache.reset_cache()
-
-
 def test_window_report_on_the_cpu(tool, process_state):
     """The report of a short window at 2^12 rows: every per-layer number,
     the program spans inside the benchmark's, and correct answers."""
@@ -112,6 +91,10 @@ def test_window_report_on_the_cpu(tool, process_state):
                                       - out["counters"]["open"]["ships"])
     for name, (count, total, own) in spans.items():
         assert count > 0 and 0 <= own <= total + 1e-9
+    # how the window was served, and the benchmark's own spans in it
+    served = out["served"]
+    assert served["txns"] == 64 * served["groups"] > 0
+    assert out["bench_spans"]["execute"][0] == served["groups"]
 
 
 @pytest.mark.parametrize("name", ["eager_wi50", "eager_ana"])

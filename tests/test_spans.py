@@ -196,12 +196,17 @@ def test_checkpoint_of_a_recording_session_is_unchanged(workload, tmp_path):
 @pytest.mark.parametrize("plane", [
     dict(n_shards=4, placement="stacked", delta_store=False),
     dict(n_shards=1, placement="stacked", delta_store=True),
-], ids=["sharded_eager", "delta"])
+    pytest.param(dict(n_shards=4, placement="mesh", delta_store=False),
+                 marks=pytest.mark.host_devices),
+], ids=["sharded_eager", "delta", "mesh4"])
 def test_other_planes_record_the_same_structure(workload, plane):
-    """Four stacked islands re-encode through `apply_updates_shards`; the
-    delta plane appends to overlays with no dictionary stages. Both
-    record ship batches, applies and scanning query groups as the eager
-    plane does, and answer as an unrecorded session of the same plane."""
+    """Four stacked islands re-encode through `apply_updates_shards`; four
+    islands on a device mesh re-encode each on its own device
+    (`apply_updates_islands`); the delta plane appends to overlays with no
+    dictionary stages. All record ship batches, applies and scanning query
+    groups as the eager plane does, an island plane a stage-3 span of its
+    rows per island, and answer as an unrecorded session of the same
+    plane. The mesh case needs four devices (``host_devices``)."""
     spec = SPEC.replace(**plane)
     session, answers = drive(workload, True, spec=spec)
     plain, plain_answers = drive(workload, False, spec=spec)
@@ -211,15 +216,40 @@ def test_other_planes_record_the_same_structure(workload, plane):
     c = session.counters()
     assert c["ships"] == names["ship_batch"] >= N_ROUNDS + 2
     assert c["query_groups"] == names["ana"] >= 2
+    islands = plane["n_shards"]
     for i, s in enumerate(spans):
         kids = collections.Counter(k.name for k in children(spans, i))
         if s.name == "ship_batch":
             assert kids["drain"] == kids["ship"] == 1 and kids["apply"] > 1
             assert kids["stages"] == (0 if plane["delta_store"] else 1)
         elif s.name == "apply" and not plane["delta_store"]:
-            assert kids == {"reencode": 1, "swap": 1}
+            assert kids == {"reencode": islands, "swap": 1}
+            assert sorted(k.n for k in children(spans, i)
+                          if k.name == "reencode") == [ROWS // islands
+                                                       ] * islands
         elif s.name == "ana":
             assert set(kids) == {"scan"}
+    if plane["placement"] == "mesh":
+        # stage 3 ran on every island's device, counted per island shard,
+        # and no column was sharded through the host
+        assert c["reencodes_device"] == islands * c["applications"]
+        assert c["reencodes_host"] == c["views_built"] == 0
+        assert c["views_resident"] > 0
+
+
+@pytest.mark.parametrize("plane", [
+    dict(n_shards=1, placement="stacked"),
+    pytest.param(dict(n_shards=4, placement="mesh"),
+                 marks=pytest.mark.host_devices),
+], ids=["one_replica", "mesh4"])
+def test_set_up_places_the_replica_in_one_span(workload, plane):
+    """A session recording from its start puts its replica on the device
+    inside one ``place`` span, counting the rows it places."""
+    table = workload[0]
+    session = HTAPSession(SPEC.replace(**plane), table, record_spans=True)
+    [place] = session.cost.spans
+    assert place.name == "place" and place.parent == -1
+    assert place.n == table.shape[0] * table.shape[1]
 
 
 def test_scan_spans_wrap_kernel_entry_points():

@@ -181,3 +181,34 @@ def test_mesh_scan_compiles_one_island_per_device(topo, family):
     text = call.lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= n_kernels
     assert "all-reduce" in text
+
+
+@pytest.mark.parametrize("family", ["reencode", "snapshot"])
+def test_island_program_compiles_and_stays_on_its_device(topo, family):
+    """Stage 3 and the snapshot copy of islands resident on their own
+    devices: one shard_map program of per-island kernels on a 4-device
+    mesh of described chips, with no collective, since each island works
+    on its own shard alone."""
+    from repro.kernels.reencode.ops import _mesh_reencode_call
+    from repro.kernels.snapshot_copy.ops import _mesh_snapshot_call
+
+    mesh = Mesh(topo.devices[:ISLANDS], (ISLAND_AXIS,))
+    island = NamedSharding(mesh, island_spec())
+    repl = NamedSharding(mesh, replicated_spec())
+    width = ROWS // ISLANDS
+    shard = [((ISLANDS, width), I32)]
+    if family == "reencode":
+        call = _mesh_reencode_call(mesh, "compiled")
+        w = 256   # the widest warm-up flush's thresholds and writes
+        args = (_shapes(island, *shard, ((ISLANDS, width), BOOL))
+                + _shapes(repl, ((w,), I32), ((1,), I32))
+                + _shapes(island, ((ISLANDS, w), I32), ((ISLANDS, w), I32),
+                          ((ISLANDS, 8), I32)))
+    else:
+        call = _mesh_snapshot_call(mesh, 8192, "compiled")
+        args = _shapes(island, *shard * 2)
+    text = call.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+    for collective in ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective

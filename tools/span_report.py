@@ -3,13 +3,16 @@
     python3 tools/span_report.py --workload eager.wi50 --seed 7 --seconds 51
     python3 tools/span_report.py --workload eager.wi50 --seed 7 \\
         --seconds 51 --recorder-cost
+    python3 tools/span_report.py --workload mesh4.wi50 --seed 7 \\
+        --seconds 51 --bench <dir>/BENCHMARK.json
 
 Run from the root of a checkout, on the chip. The first form opens a cell
 of the chip benchmark (``chipbench/``) as ``chipbench.run`` does, turns the
 session's span recorder on after the warm-up (``CostLog.record_spans``),
 serves the window with a profiler trace of its middle, checks the answers
 against the reference and prints one JSON line: the per-layer numbers the
-program's spans and counters give over the window (``metrics``), how much
+program's spans and counters give over the window (``metrics``), how the
+window was served and the benchmark's own spans in it, how much
 of the benchmark's own ``flush`` and ``query_batch`` spans the program's
 spans cover, each span name's count and total and self seconds, the spans
 recorded a second and what one costs, the device idle time split by the
@@ -19,7 +22,8 @@ the trace.
 
 The second form is one ``--trace 0`` run of ``chipbench.run`` with the
 recorder on from the session's start: set beside a plain ``--trace 0``
-run of the same seed, it is what recording costs.
+run of the same seed, it is what recording costs. The third reads a cell
+from another benchmark file, with its own ``chipbench/`` beside it.
 """
 
 from __future__ import annotations
@@ -98,6 +102,16 @@ def layer_metrics(totals: dict, n_groups: int, traces: tuple[int, int],
         out.update(snapshot_ms_per_group=ms("snapshot") / groups,
                    scan_ms_per_group=ms("scan") / groups,
                    query_glue_ms_per_group=1e3 * totals["ana"][2] / groups)
+    return out
+
+
+def bench_totals(bench_spans) -> dict:
+    """name -> [count, seconds] of the benchmark's own spans."""
+    out = {}
+    for name, a, b in bench_spans:
+        row = out.setdefault(name, [0, 0.0])
+        row[0] += 1
+        row[1] += b - a
     return out
 
 
@@ -201,11 +215,13 @@ class Tracer(run.Tracer):
 
 
 def report(workload: str, seed: int, seconds: float, *,
-           require_chip: bool = True, overrides: dict | None = None) -> dict:
+           require_chip: bool = True, overrides: dict | None = None,
+           bench: Path | None = None) -> dict:
     """One window of ``workload`` with the span recorder on (see the
-    module docstring)."""
+    module docstring); ``bench`` is the benchmark file that names the
+    cell, ``BENCHMARK.json`` by default."""
     o = run.open_cell(workload, seed, require_chip=require_chip,
-                      overrides=overrides)
+                      overrides=overrides, bench=bench)
     loop, session = o.loop, o.loop.session
     queries, due = run.window_traffic(seed, o.config, o.traffic, seconds)
     session.cost.record_spans()
@@ -242,7 +258,11 @@ def report(workload: str, seed: int, seconds: float, *,
            "spans": totals,
            "counters": counters,
            "spans_per_s": sum(c for c, _, _ in totals.values()) / (t1 - t0),
-           "span_cost_us": 1e6 * span_cost_s()}
+           "span_cost_us": 1e6 * span_cost_s(),
+           "served": {**run.served_summary(served, seconds),
+                      "txns": sum(n for _, _, n in served.groups),
+                      "window_s": t1 - t0},
+           "bench_spans": bench_totals(bench)}
     if device is not None:
         idle = device["idle_s"]
         out["metrics"]["idle_unattributed"] = (
@@ -262,13 +282,17 @@ def main(argv=None) -> int:
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--recorder-cost", action="store_true",
                    help="one --trace 0 benchmark run, recorder on")
+    p.add_argument("--bench", type=Path, default=None,
+                   help="the benchmark file naming the cell, with its "
+                   "chipbench/ beside it (default: BENCHMARK.json)")
     args = p.parse_args(argv)
     if args.recorder_cost:
         return run.main(["--workload", args.workload, "--seed",
                          str(args.seed), "--seconds", str(args.seconds),
-                         "--trace", "0"], make_system=recording_system)
-    print(json.dumps(report(args.workload, args.seed, args.seconds)),
-          flush=True)
+                         "--trace", "0"], make_system=recording_system,
+                        bench=args.bench)
+    print(json.dumps(report(args.workload, args.seed, args.seconds,
+                            bench=args.bench)), flush=True)
     return 0
 
 

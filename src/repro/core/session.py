@@ -314,9 +314,12 @@ class HTAPSession:
         self.spec = spec
         # start from a clean jit-trace ledger so finish()'s
         # stats["traces"] covers exactly THIS session's lifetime (ad-hoc
-        # kernel calls between sessions never leak into it)
-        from repro.kernels.common import reset_kernel_trace_counts
+        # kernel calls between sessions never leak into it); the same for
+        # the scans' decode counts of counters()
+        from repro.kernels.common import (reset_decode_counts,
+                                          reset_kernel_trace_counts)
         reset_kernel_trace_counts()
+        reset_decode_counts()
         self.timing = resolve_timing(spec.timing)
         if spec.async_propagation and self.timing != "timeline":
             raise ValueError(
@@ -517,11 +520,15 @@ class HTAPSession:
         and view counts that `finish` reports, and ``reencodes_device`` /
         ``reencodes_host``: the column shards (one per island, one for an
         unsharded column) whose stage-3 re-encode ran on the device / on
-        the host."""
-        from repro.kernels.common import kernel_trace_counts
+        the host. ``scan_decodes_select`` / ``_hist`` / ``_gather`` count
+        the scans' column decodes by path (`dict_ops.decode_path`; an
+        aggregate column and a join build side are one each)."""
+        from repro.kernels.common import decode_counts, kernel_trace_counts
         out = {"query_groups": sum(t.kind == "ana"
                                    for t in self.cost.tags.values()),
                "kernel_traces": sum(kernel_trace_counts().values())}
+        out.update((f"scan_decodes_{path}", n)
+                   for path, n in decode_counts().items())
         if self.spec.kind == "multi_instance":
             cons = self.cons
             out.update(ships=self._ship_i, applications=self.applications,

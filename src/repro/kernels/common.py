@@ -220,6 +220,26 @@ def reset_kernel_trace_counts() -> None:
     _trace_counts.clear()
 
 
+# Column decodes of the scans by path (``dict_ops.decode_path``): an
+# aggregate column and a join build side are one decode each. Counted on
+# the host at dispatch, so a compiled-cache hit counts too.
+DECODE_PATHS = ("select", "hist", "gather")
+_decode_counts = dict.fromkeys(DECODE_PATHS, 0)
+
+
+def count_decode(path: str) -> None:
+    _decode_counts[path] += 1
+
+
+def decode_counts() -> dict[str, int]:
+    """Column decodes per path since the last reset (a copy)."""
+    return dict(_decode_counts)
+
+
+def reset_decode_counts() -> None:
+    _decode_counts.update(dict.fromkeys(DECODE_PATHS, 0))
+
+
 def instrumented_jit(fn=None, *, static_argnames=(), donate_argnums=(),
                      name: str | None = None):
     """``jax.jit`` that counts every (re)trace of the wrapped function.

@@ -27,10 +27,13 @@ from repro.distributed.sharding import (ISLAND_AXIS, island_spec,
                                         replicated_spec)
 from repro.kernels.bitonic_sort.bitonic_sort import (bitonic_merge_rows,
                                                      bitonic_sort_rows)
-from repro.kernels.common import (LANES, donation_enabled, instrumented_jit,
-                                  kernel_mode, lanes_to_int64, next_pow2,
-                                  psum_split16, width_bucket)
-from repro.kernels.dict_ops.dict_ops import (scan_filter_agg_exact_kernel,
+from repro.kernels.common import (LANES, count_decode, donation_enabled,
+                                  instrumented_jit, kernel_mode,
+                                  lanes_to_int64, next_pow2, psum_split16,
+                                  width_bucket)
+from repro.kernels.dict_ops.dict_ops import (DICT_SELECT_MAX, decode_path,
+                                             scan_exact_decoded,
+                                             scan_filter_agg_exact_kernel,
                                              scan_filter_agg_sharded_kernel,
                                              scan_values_agg_exact_kernel)
 from repro.kernels.dict_ops.lowered import (apply_pipeline_lowered,
@@ -49,11 +52,6 @@ from repro.kernels.dict_ops.ref import (scan_filter_agg_batch_ref,
                                         scan_values_agg_ref)
 
 _I32_MAX = np.iinfo(np.int32).max
-# The widest padded dictionary XLA decodes by a compare/select chain: on a
-# v5e a 2^24-row decode takes about 3 ms at 32 or 64 entries, and XLA's
-# native gather takes 115-166 ms at every width from 128 to 2^17 (PERF.md,
-# section 7).
-DICT_SELECT_MAX = 64
 # The least width a wider dictionary operand is padded to. Every program
 # that decodes through a dictionary compiles once per padded width, a few
 # seconds each on the chip's host, so a column whose dictionary grows
@@ -108,17 +106,49 @@ def assemble_exact(lo16, hi16, cnt, neg, axis):
     return sums, counts
 
 
+def count_decodes(*dictionaries, hist: bool = False) -> None:
+    """Count one decode per (padded) dictionary, by the path
+    `dict_ops.decode_path` gives a program with (``hist``) or without the
+    histogram branch."""
+    for d in dictionaries:
+        count_decode(decode_path(d.shape[0], hist))
+
+
+def live_length(dictionary) -> np.ndarray:
+    """The (1,) int32 live length of an unpadded dictionary, the
+    histogram's scalar operand (`dict_ops._hist_counts`)."""
+    return np.asarray([dictionary.shape[0]], dtype=np.int32)
+
+
+def exact_totals(parts, dictionary):
+    """Exact int64 (sums, counts), one per padded query, of the outputs of
+    `dict_ops.scan_filter_agg_exact_kernel`: split-sum partials
+    (`assemble_exact`), or a 1-tuple of code counts, dotted in int64 with
+    the unpadded ``dictionary`` (no code reaches past its length)."""
+    if len(parts) != 1:
+        return assemble_exact(*parts, axis=0)
+    (hist,) = parts
+    k = dictionary.shape[0]
+    counts = np.asarray(hist).reshape(hist.shape[0], -1)[:, :k]
+    counts = counts.astype(np.int64)
+    return (counts @ np.asarray(dictionary).astype(np.int64),
+            counts.sum(axis=1))
+
+
 def scan_exact_dispatch(fcodes, acodes, valid, dictionary, bounds,
-                        block: int):
-    """Mode-dispatched exact scan over RAW (unpadded) flat columns: same
-    (nb, Q) int32 partials either way. `dictionary` must be pow2-padded,
-    `bounds` a host (pow2(Q), 2) int32 array."""
+                        block: int, live):
+    """Mode-dispatched exact scan over RAW (unpadded) flat columns, its
+    decode counted: the outputs `scan_filter_agg_exact_kernel` gives for
+    its decode path (the lowered path's partials are the kernel's).
+    `dictionary` must be pow2-padded, `bounds` a host (pow2(Q), 2) int32
+    array, ``live`` the dictionary's `live_length`."""
     mode = kernel_mode()
+    count_decodes(dictionary, hist=(mode != "lowered"))
     if mode == "lowered":
         return scan_exact_lowered(fcodes, acodes, valid, dictionary, bounds,
                                   block=block)
     return scan_filter_agg_exact_kernel(fcodes, acodes, valid, dictionary,
-                                        bounds, block=block,
+                                        bounds, live, block=block,
                                         interpret=(mode == "interpret"))
 
 
@@ -167,10 +197,10 @@ def scan_filter_agg_batch(fcodes, acodes, valid, dictionary, bounds,
     if n == 0 or not len(bounds):
         return [(0, 0) for _ in bounds]
     nq = len(bounds)
-    lo16, hi16, cnt, neg = scan_exact_dispatch(
+    parts = scan_exact_dispatch(
         fcodes, acodes, valid, pad_dictionary_pow2(dictionary),
-        pad_bounds_pow2(bounds), block)
-    sums, counts = assemble_exact(lo16, hi16, cnt, neg, axis=0)
+        pad_bounds_pow2(bounds), block, live_length(dictionary))
+    sums, counts = exact_totals(parts, dictionary)
     return [(int(s), int(c)) for s, c in zip(sums[:nq], counts[:nq])]
 
 
@@ -194,9 +224,10 @@ def scan_filter_agg_sharded(fcodes, acodes, valid, dictionary, bounds,
     # bucket the block to the (pow2) shard width so small shards don't pad
     # a 4096-wide tile each
     block = min(block, next_pow2(width))
+    dpad = pad_dictionary_pow2(dictionary)
+    count_decodes(dpad)
     lo16, hi16, cnt, neg = scan_exact_sharded_dispatch(
-        fcodes, acodes, valid, pad_dictionary_pow2(dictionary),
-        pad_bounds_pow2(bounds), block)
+        fcodes, acodes, valid, dpad, pad_bounds_pow2(bounds), block)
     sums, counts = assemble_exact(lo16, hi16, cnt, neg, axis=1)
     return [[(int(sums[s, q]), int(counts[s, q])) for q in range(nq)]
             for s in range(n_shards)]
@@ -253,9 +284,8 @@ def scan_values_agg(fvals, avals, valid, bounds, use_pallas: bool = True,
 
 def _scan_group_kernel_body(fcodes, acodes, valid, dictionary, bounds, corr,
                             vbounds, block, cblock, interpret):
-    base = scan_filter_agg_exact_kernel(fcodes, acodes, valid, dictionary,
-                                        bounds, block=block,
-                                        interpret=interpret)
+    base = scan_exact_decoded(fcodes, acodes, valid, dictionary, bounds,
+                              block, interpret)
     eff = scan_values_agg_exact_kernel(corr[0], corr[1], corr[2], vbounds,
                                        block=cblock, interpret=interpret)
     neg = scan_values_agg_exact_kernel(corr[3], corr[4], corr[5], vbounds,
@@ -358,6 +388,7 @@ def scan_filter_agg_group(fcodes, acodes, valid, dictionary, code_bounds,
     barr = pad_bounds_pow2(code_bounds)
     varr = pad_bounds_pow2(vbounds)
     dpad = pad_dictionary_pow2(dictionary)
+    count_decodes(dpad)
     mode = kernel_mode()
     if mode == "lowered":
         parts = scan_group_lowered(fcodes, acodes, valid, dpad, barr,
@@ -392,6 +423,7 @@ def scan_filter_agg_group_sharded(fcodes, acodes, valid, dictionary,
     barr = pad_bounds_pow2(code_bounds)
     varr = pad_bounds_pow2(vbounds)
     dpad = pad_dictionary_pow2(dictionary)
+    count_decodes(dpad)
     mode = kernel_mode()
     if mode == "lowered":
         parts = scan_group_sharded_lowered(fcodes, acodes, valid, dpad,
@@ -539,8 +571,9 @@ def scan_filter_agg_mesh(fcodes, acodes, valid, dictionary, bounds, mesh,
     if width == 0 or nq == 0:
         return [(0, 0)] * nq
     block = min(block, next_pow2(width))
+    dpad = pad_dictionary_pow2(dictionary)
+    count_decodes(dpad)
     lanes = _mesh_scan_call(mesh, block, kernel_mode())(
-        fcodes, acodes, valid, pad_dictionary_pow2(dictionary),
-        pad_bounds_pow2(bounds))
+        fcodes, acodes, valid, dpad, pad_bounds_pow2(bounds))
     sums, counts = assemble_psum_lanes(lanes)
     return [(int(sums[q]), int(counts[q])) for q in range(nq)]

@@ -27,16 +27,17 @@ from repro.distributed.sharding import (ISLAND_AXIS, island_spec,
                                         replicated_spec)
 from repro.kernels.common import (instrumented_jit, kernel_mode, next_pow2,
                                   psum_split16)
-from repro.kernels.dict_ops.dict_ops import (scan_filter_agg_exact_kernel,
+from repro.kernels.dict_ops.dict_ops import (scan_exact_decoded,
+                                             scan_filter_agg_exact_kernel,
                                              scan_filter_agg_sharded_kernel,
                                              scan_values_agg_exact_kernel)
 from repro.kernels.dict_ops.lowered import (scan_exact_partials,
                                             scan_exact_sharded_partials,
                                             scan_values_partials)
 from repro.kernels.dict_ops.ops import (_padded_corr, assemble_exact,
-                                        assemble_psum_lanes,
-                                        pad_bounds_pow2,
-                                        pad_dictionary_pow2)
+                                        assemble_psum_lanes, count_decodes,
+                                        exact_totals, live_length,
+                                        pad_bounds_pow2, pad_dictionary_pow2)
 from repro.kernels.hash_probe.hash_probe import (EMPTY, probe_table,
                                                  probe_table_sharded)
 from repro.kernels.hash_probe.lowered import (probe_lowered,
@@ -211,21 +212,25 @@ def _join_scan_lowered(fcodes, acodes, jcodes, fvalid, jvalid, adict,
     agg = scan_exact_partials(fcodes, acodes, fv, adict, bounds, block)
     join = scan_exact_partials(fcodes, jcodes, fv * jv, rcount,
                                bounds, block)
-    return agg + join
+    return agg, join
 
 
 @functools.partial(instrumented_jit, static_argnames=("block", "interpret"))
 def _join_scan_pallas(fcodes, acodes, jcodes, fvalid, jvalid, adict,
-                      rcount, bounds, block: int = 4096,
+                      rcount, bounds, alive, rlive, block: int = 4096,
                       interpret: bool = True):
+    """The aggregate and the join scan's outputs, each by the decode its
+    own padded width picks (`scan_filter_agg_exact_kernel`); ``alive`` and
+    ``rlive`` are the live lengths of ``adict`` and ``rcount``."""
     fcodes, acodes, jcodes, fv, jv = _pad_join_rows(
         fcodes, acodes, jcodes, fvalid, jvalid, block)
     agg = scan_filter_agg_exact_kernel(fcodes, acodes, fv, adict, bounds,
-                                       block=block, interpret=interpret)
+                                       alive, block=block,
+                                       interpret=interpret)
     join = scan_filter_agg_exact_kernel(fcodes, jcodes, fv * jv,
-                                        rcount, bounds, block=block,
+                                        rcount, bounds, rlive, block=block,
                                         interpret=interpret)
-    return agg + join
+    return agg, join
 
 
 @functools.partial(instrumented_jit, static_argnames=("block",))
@@ -270,16 +275,18 @@ def scan_filter_agg_join(fcodes, acodes, jcodes, fvalid, jvalid, adict,
     if n == 0 or nq == 0:
         return [(0, 0, 0) for _ in range(nq)]
     mode = kernel_mode()
-    args = (fcodes, acodes, jcodes, fvalid, jvalid,
-            pad_dictionary_pow2(adict), pad_dictionary_pow2(rcount),
+    apad, rpad = pad_dictionary_pow2(adict), pad_dictionary_pow2(rcount)
+    count_decodes(apad, rpad, hist=(mode != "lowered"))
+    args = (fcodes, acodes, jcodes, fvalid, jvalid, apad, rpad,
             pad_bounds_pow2(bounds))
     if mode == "lowered":
-        parts = _join_scan_lowered(*args, block=block)
+        agg, join = _join_scan_lowered(*args, block=block)
     else:
-        parts = _join_scan_pallas(*args, block=block,
-                                  interpret=(mode == "interpret"))
-    sums, counts = assemble_exact(*parts[:4], axis=0)
-    jsums, _ = assemble_exact(*parts[4:], axis=0)
+        agg, join = _join_scan_pallas(*args, live_length(adict),
+                                      live_length(rcount), block=block,
+                                      interpret=(mode == "interpret"))
+    sums, counts = exact_totals(agg, adict)
+    jsums, _ = exact_totals(join, rcount)
     return [(int(sums[q]), int(counts[q]), int(jsums[q]))
             for q in range(nq)]
 
@@ -304,6 +311,7 @@ def scan_filter_agg_join_sharded(fcodes, acodes, jcodes, fvalid, jvalid,
     args = (fcodes, acodes, jcodes, fvalid, jvalid,
             pad_dictionary_pow2(adict), pad_dictionary_pow2(rcount),
             pad_bounds_pow2(bounds))
+    count_decodes(*args[5:7])
     if mode == "lowered":
         parts = _join_scan_sharded_lowered(*args, block=block)
     else:
@@ -345,11 +353,10 @@ def _join_group_pallas_body(fcodes, acodes, jcodes, fvalid, jvalid, adict,
                             cblock_a, cblock_j, interpret):
     fcodes, acodes, jcodes, fv, jv = _pad_join_rows(
         fcodes, acodes, jcodes, fvalid, jvalid, block)
-    agg = scan_filter_agg_exact_kernel(fcodes, acodes, fv, adict, bounds,
-                                       block=block, interpret=interpret)
-    join = scan_filter_agg_exact_kernel(fcodes, jcodes, fv * jv, rcount,
-                                        bounds, block=block,
-                                        interpret=interpret)
+    agg = scan_exact_decoded(fcodes, acodes, fv, adict, bounds, block,
+                             interpret)
+    join = scan_exact_decoded(fcodes, jcodes, fv * jv, rcount, bounds,
+                              block, interpret)
     ae = scan_values_agg_exact_kernel(corr_a[0], corr_a[1], corr_a[2],
                                       vbounds, block=cblock_a,
                                       interpret=interpret)
@@ -400,6 +407,7 @@ def scan_filter_agg_join_group(fcodes, acodes, jcodes, fvalid, jvalid,
     args = (fcodes, acodes, jcodes, fvalid, jvalid,
             pad_dictionary_pow2(adict), pad_dictionary_pow2(rcount),
             pad_bounds_pow2(code_bounds), ca, cj, pad_bounds_pow2(vbounds))
+    count_decodes(*args[5:7])
     mode = kernel_mode()
     if mode == "lowered":
         parts = _join_group_lowered(*args, block=block, cblock_a=cblock_a,
@@ -472,9 +480,10 @@ def scan_filter_agg_join_mesh(fcodes, acodes, jcodes, fvalid, jvalid,
     if width == 0 or nq == 0:
         return [(0, 0, 0)] * nq
     block = min(block, next_pow2(width))
+    apad, rpad = pad_dictionary_pow2(adict), pad_dictionary_pow2(rcount)
+    count_decodes(apad, rpad)
     lanes = _mesh_join_call(mesh, block, kernel_mode())(
-        fcodes, acodes, jcodes, fvalid, jvalid,
-        pad_dictionary_pow2(adict), pad_dictionary_pow2(rcount),
+        fcodes, acodes, jcodes, fvalid, jvalid, apad, rpad,
         pad_bounds_pow2(bounds))
     sums, counts = assemble_psum_lanes(lanes[:8])
     jsums, _ = assemble_psum_lanes(lanes[8:])

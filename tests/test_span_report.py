@@ -75,11 +75,18 @@ def test_window_report_on_the_cpu(tool, process_state):
         "rowstore_ms_per_group", "ship_ms_per_ship", "reencode_ms_per_ship",
         "reencodes_device", "reencodes_host", "reencode_device_share",
         "stages_ms_per_ship", "snapshot_ms_per_group", "scan_ms_per_group",
-        "query_glue_ms_per_group", "retraces_in_window"}
+        "query_glue_ms_per_group", "retraces_in_window",
+        "scan_decodes_select", "scan_decodes_hist", "scan_decodes_gather",
+        "hist_decode_share"}
     assert all(v >= 0 for v in out["metrics"].values())
+    # the lowered CPU path decodes every aggregate and build side by XLA's
+    # take, one decode a column of each query
+    metrics = out["metrics"]
+    assert metrics["scan_decodes_hist"] == 0 == metrics["hist_decode_share"]
+    assert metrics["scan_decodes_select"] + metrics["scan_decodes_gather"] \
+        >= out["spans"]["scan"][0]
     # the eager plane's replica is on the device: every column of the
     # window's ships re-encoded there
-    metrics = out["metrics"]
     assert metrics["reencodes_device"] > 0 == metrics["reencodes_host"]
     assert metrics["reencode_device_share"] == 100.0
     # the window's one batch is due at its start, when the warm-up has

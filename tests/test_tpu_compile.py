@@ -13,7 +13,10 @@ only one process may load the TPU library, so a describe at import would
 make pytest-xdist workers collect different tests.
 """
 
+import base64
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +27,7 @@ from repro.distributed.sharding import (ISLAND_AXIS, island_spec,
                                         replicated_spec)
 from repro.kernels.bitonic_sort.bitonic_sort import (bitonic_merge_rows,
                                                      bitonic_sort_rows)
+from repro.kernels.dict_ops import dict_ops as dict_ops_kernels
 from repro.kernels.dict_ops import ops as dict_ops
 from repro.kernels.dict_ops.dict_ops import (scan_filter_agg_exact_kernel,
                                              scan_filter_agg_sharded_kernel)
@@ -82,7 +86,7 @@ CASES = {
     "scan_exact": (
         scan_filter_agg_exact_kernel,
         [((ROWS,), I32), ((ROWS,), I32), ((ROWS,), BOOL),
-         ((SCAN_DICT,), I32), ((Q, 2), I32)],
+         ((SCAN_DICT,), I32), ((Q, 2), I32), ((1,), I32)],
         dict(block=BLOCK), 1),
     "scan_sharded": (
         scan_filter_agg_sharded_kernel,
@@ -109,7 +113,8 @@ CASES = {
     "join_scan": (
         hash_ops._join_scan_pallas,
         [((ROWS,), I32)] * 3 + [((ROWS,), BOOL)] * 2
-        + [((SCAN_DICT,), I32), ((SCAN_DICT,), I32), ((Q, 2), I32)],
+        + [((SCAN_DICT,), I32), ((SCAN_DICT,), I32), ((Q, 2), I32),
+           ((1,), I32), ((1,), I32)],
         dict(block=BLOCK), 2),
     "join_group": (
         hash_ops._join_group_pallas,
@@ -155,6 +160,93 @@ def test_kernel_compiles_for_v5e(one_chip, name):
                         **static).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= n_kernels, name
+
+
+@pytest.mark.parametrize("family", ["scan", "join"])
+def test_histogram_branch_compiles_for_v5e(one_chip, family):
+    """Past `DICT_SELECT_MAX` padded entries, one query counts its codes
+    on the MXU: the flat programs keep their names, hold a kernel per
+    histogram and gather nothing."""
+    assert dict_ops.decode_path(SCAN_DICT) == "hist"
+    if family == "scan":
+        fn, n_kernels = scan_filter_agg_exact_kernel, 1
+        specs = [((ROWS,), I32), ((ROWS,), I32), ((ROWS,), BOOL),
+                 ((SCAN_DICT,), I32), ((1, 2), I32), ((1,), I32)]
+    else:
+        fn, n_kernels = hash_ops._join_scan_pallas, 2
+        specs = ([((ROWS,), I32)] * 3 + [((ROWS,), BOOL)] * 2
+                 + [((SCAN_DICT,), I32)] * 2 + [((1, 2), I32)]
+                 + [((1,), I32)] * 2)
+    compiled = fn.lower(*_shapes(one_chip, *specs), block=BLOCK,
+                        interpret=False).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == n_kernels
+    assert "gather" not in text
+
+
+def _without_locations(lowered) -> str:
+    """A lowering's text with each kernel body printed without its source
+    locations, which name the lines of the Python that traced it, and
+    without the results' pytree paths (``jax.result_info``), which name
+    how the Python nests its outputs."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+
+    def body(m):
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+        return module.operation.get_asm(enable_debug_info=False)
+
+    text = re.sub(r"body\\22: \\22([A-Za-z0-9+/=]+)\\22", body,
+                  lowered.as_text())
+    return re.sub(r" \{jax\.result_info = \"[^\"]*\"\}", "", text)
+
+
+@pytest.mark.parametrize("family", ["scan", "join"])
+@pytest.mark.parametrize("width", [32, dict_ops.DICT_SELECT_MAX])
+def test_select_branch_lowers_as_the_decoding_scan(one_chip, family, width):
+    """Up to `DICT_SELECT_MAX` padded entries the flat programs lower to
+    what they lowered to before the histogram branch: the dictionary
+    take in XLA around the split-sum kernel, the live lengths unused."""
+
+    @functools.partial(jax.jit, static_argnames=("block", "interpret"))
+    def scan_filter_agg_exact_kernel(fcodes, acodes, valid, dictionary,
+                                     bounds, block=4096, interpret=True):
+        parts = dict_ops_kernels._scan_partials(
+            bounds, fcodes[None],
+            dict_ops_kernels._decode(dictionary, acodes)[None], valid[None],
+            block, False, interpret)
+        return tuple(p[0] for p in parts)
+
+    @functools.partial(jax.jit, static_argnames=("block", "interpret"))
+    def _join_scan_pallas(fcodes, acodes, jcodes, fvalid, jvalid, adict,
+                          rcount, bounds, block=4096, interpret=True):
+        fcodes, acodes, jcodes, fv, jv = hash_ops._pad_join_rows(
+            fcodes, acodes, jcodes, fvalid, jvalid, block)
+        return (scan_filter_agg_exact_kernel(
+                    fcodes, acodes, fv, adict, bounds, block=block,
+                    interpret=interpret)
+                + scan_filter_agg_exact_kernel(
+                    fcodes, jcodes, fv * jv, rcount, bounds, block=block,
+                    interpret=interpret))
+
+    if family == "scan":
+        want, got = scan_filter_agg_exact_kernel, dict_ops_kernels.\
+            scan_filter_agg_exact_kernel
+        specs = [((ROWS,), I32), ((ROWS,), I32), ((ROWS,), BOOL),
+                 ((width,), I32), ((Q, 2), I32)]
+    else:
+        want, got = _join_scan_pallas, hash_ops._join_scan_pallas
+        specs = ([((ROWS,), I32)] * 3 + [((ROWS,), BOOL)] * 2
+                 + [((width,), I32)] * 2 + [((Q, 2), I32)])
+    args = _shapes(one_chip, *specs)
+    live = _shapes(one_chip, *[((1,), I32)] * (1 if family == "scan" else 2))
+    assert dict_ops.decode_path(width) == "select"
+    assert (_without_locations(got.lower(*args, *live, interpret=False))
+            == _without_locations(want.lower(*args, interpret=False)))
 
 
 @pytest.mark.parametrize("family", ["scan", "join"])

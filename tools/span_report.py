@@ -45,6 +45,7 @@ from chipbench.stats import union  # noqa: E402
 # The benchmark's spans whose time the program's spans should account for.
 COVERS = {"flush": ("ship_batch",), "query_batch": ("snapshot", "ana")}
 NO_SPAN = "no_program_span"
+DECODES = "scan_decodes_"   # counters of the scans' decodes, by path
 
 
 def recording_system(config, table):
@@ -72,13 +73,16 @@ def span_totals(spans, t0: float, t1: float) -> dict:
 
 
 def layer_metrics(totals: dict, n_groups: int, traces: tuple[int, int],
-                  reencodes: tuple[int, int] = (0, 0)) -> dict:
+                  reencodes: tuple[int, int] = (0, 0),
+                  decodes: dict | None = None) -> dict:
     """The per-layer numbers of a window from its span totals
     (`span_totals`): milliseconds per commit group, per ship batch and per
-    query group, the kernel traces the window added, and, next to the
-    re-encode's milliseconds, the columns whose stage 3 the window ran on
+    query group, the kernel traces the window added, next to the
+    re-encode's milliseconds the columns whose stage 3 the window ran on
     the device and on the host (``reencodes``) with the device's share of
-    them in percent."""
+    them in percent, and next to the scan's milliseconds the window's
+    column decodes by path (``decodes``, path -> count) with the
+    histogram's share of them in percent."""
 
     def count(name):
         return totals.get(name, [0, 0.0, 0.0])[0]
@@ -102,6 +106,11 @@ def layer_metrics(totals: dict, n_groups: int, traces: tuple[int, int],
         out.update(snapshot_ms_per_group=ms("snapshot") / groups,
                    scan_ms_per_group=ms("scan") / groups,
                    query_glue_ms_per_group=1e3 * totals["ana"][2] / groups)
+        decodes = decodes or {}
+        n = sum(decodes.values())
+        out.update({f"scan_decodes_{p}": c for p, c in decodes.items()},
+                   hist_decode_share=(100.0 * decodes.get("hist", 0) / n
+                                      if n else 0.0))
     return out
 
 
@@ -253,7 +262,10 @@ def report(workload: str, seed: int, seconds: float, *,
                                     (counters["open"]["kernel_traces"],
                                      counters["close"]["kernel_traces"]),
                                     (added("reencodes_device"),
-                                     added("reencodes_host"))),
+                                     added("reencodes_host")),
+                                    {k[len(DECODES):]: added(k)
+                                     for k in counters["close"]
+                                     if k.startswith(DECODES)}),
            "covers": coverage(bench, session.cost.spans),
            "spans": totals,
            "counters": counters,
